@@ -297,8 +297,9 @@ class DenseCodec(Codec):
 class BitmapCodec(Codec):
     """``ceil(size/8)`` bitmap bytes (LSB-first) + set-bit values in index
     order. Duplicate indices are coalesced by summation. The bit-pack has a
-    Pallas kernel path (``repro.kernels.bitpack``, interpret-mode on CPU)
-    selectable with ``impl="pallas"``; both paths emit identical bytes."""
+    Pallas kernel path (``repro.kernels.bitpack``; compiled on a TPU,
+    interpreted on the CPU) selectable with ``impl="pallas"``; both paths
+    emit identical bytes."""
 
     def __init__(self, name: str, fmt: str, aliases: Tuple[str, ...] = ()):
         self.name = name

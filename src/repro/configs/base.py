@@ -343,8 +343,8 @@ class HFLConfig:
     # Ω selection implementation for the sync payloads:
     #   topk (exact lax.top_k) | hist (jnp histogram threshold) |
     #   pallas (kernels/dgc hist passes) | fused (kernels/fused_sync —
-    #   threshold+mask+compaction in one pass, selection bit-identical
-    #   to topk without its whole-vector sort)
+    #   threshold select + compaction + a small finisher top-k, selection
+    #   bit-identical to topk without its whole-vector sort)
     omega_impl: str = "topk"
     # sync buffer layout: "flat" runs the paper's whole-model Ω once per
     # sync over one contiguous vector (one top-k + one all-gather + one

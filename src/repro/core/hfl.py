@@ -231,13 +231,12 @@ def _make_flat_local_sync(hfl_cfg, wire, collect_stats: bool = False):
         N = hfl_cfg.num_clusters
         wref, ref_spec = fl.pack(state.w_ref)
         e, _ = fl.pack(state.e)
-        wn, p_spec = fl.pack_stacked(state.params)
-        eps, eps_spec = fl.pack_stacked(state.eps)
+        p_spec = fl.spec_of_stacked(state.params)
         Q = ref_spec.total
 
         # --- SBS side: drift + discounted error, whole-vector top-k uplink
         #     (Alg.5 l.24-27, Ω over V ∈ R^Q) ---
-        s = wn - wref[None, :] + hfl_cfg.tiers[1].beta_up * eps  # [N, Q]
+        s, eps_spec = _pack_drift(state, hfl_cfg.tiers[1].beta_up)  # [N, Q]
         sents, new_eps, ul_idx = [], [], []
         for n in range(N):  # static unroll; N is small
             vals, idx = sp.pack_phi(s[n], hfl_cfg.tiers[1].phi_up, impl=impl)
@@ -268,6 +267,7 @@ def _make_flat_local_sync(hfl_cfg, wire, collect_stats: bool = False):
         )
         if not collect_stats:
             return new_state
+        wn, _ = fl.pack_stacked(state.params)
         return new_state, _flat_sync_stats(
             wn, eps_stacked, new_e, new_wref, d, jnp.stack(ul_idx), didx)
 
@@ -414,7 +414,7 @@ def _scatter_rows(idx, vals, L: int):
 
 
 def _make_flat_fused_local_sync(hfl_cfg, wire, collect_stats: bool = False):
-    """Single-process whole-vector sync via the fused select kernel.
+    """Single-process whole-vector sync via the fused top-k select.
 
     Protocol-identical to ``_make_flat_local_sync`` (selection is
     bit-identical to ``omega_impl="topk"``), restructured for the fused

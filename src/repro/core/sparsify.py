@@ -7,9 +7,10 @@ zeroes the rest. Two selection implementations:
   * ``hist``  -- histogram threshold estimation (TPU adaptation of DGC's
                  sampled radix-select; the Pallas kernel in
                  ``repro.kernels.dgc`` implements the same two-pass scheme)
-  * ``fused`` -- exact top-k via the fused threshold/mask/compaction
-                 kernel (``repro.kernels.fused_sync``): bit-identical
-                 selection to ``topk`` without the whole-vector TopK sort
+  * ``fused`` -- exact top-k via threshold select + compaction + a small
+                 finisher top-k (``repro.kernels.fused_sync``):
+                 bit-identical selection to ``topk`` without the
+                 whole-vector TopK sort
 
 All functions operate on a single array (a leaf or a flat vector); pytree
 orchestration lives in ``repro.core.hfl``.
@@ -178,7 +179,7 @@ def pack_phi(x, phi: float, *, impl: str = "topk", bins: int = 64):
       * ``hist``   -- jnp histogram threshold + O(Q) compaction
       * ``pallas`` -- threshold from the Pallas DGC hist kernels
                       (``repro.kernels.dgc``) + O(Q) compaction
-      * ``fused``  -- the fused threshold/mask/compaction kernel
+      * ``fused``  -- threshold select + compaction + finisher
                       (``repro.kernels.fused_sync``): selection
                       bit-identical to ``topk`` without its full sort
     """

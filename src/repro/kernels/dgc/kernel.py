@@ -8,9 +8,11 @@ VPU executes on (8,128)-aligned tiles streaming HBM->VMEM once per pass:
                         across the sequential TPU grid)
   3. ``apply_mask``   : ĝ = v'·[|v'| >= th] ; u'' = u'·¬mask ; v'' = v'·¬mask
 
-The threshold pick between passes 2 and 3 is O(bins) glue in jnp. All kernels
-are validated against ``ref.py`` in interpret mode (this container is
-CPU-only; TPU is the compile target).
+The threshold pick between passes 2 and 3 is O(bins) glue in jnp. Every
+``pallas_call`` wrapper takes ``interpret`` as a required keyword; the ops
+layer derives it from the platform (``repro.kernels.interpret_mode``).
+Per-block scalars leave the kernels as whole (8,128) tiles or through
+SMEM, the layouts the TPU compiler accepts for them.
 """
 from __future__ import annotations
 
@@ -19,6 +21,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 LANES = 128
 SUBLANES = 8
@@ -41,11 +44,12 @@ def _update_max_kernel(sigma_ref, u_ref, v_ref, g_ref, u_out, v_out, max_out):
     v_new = v_ref[...] + u_new
     u_out[...] = u_new
     v_out[...] = v_new
-    max_out[0, 0] = jnp.max(jnp.abs(v_new))
+    max_out[...] = jnp.full((SUBLANES, LANES), jnp.max(jnp.abs(v_new)))
 
 
-def update_max(u, v, g, sigma, *, interpret=True):
-    """u,v,g [R, BLOCK_COLS] f32 -> (u', v', block_max [R/BR, 1])."""
+def update_max(u, v, g, sigma, *, interpret):
+    """u,v,g [R, BLOCK_COLS] f32 -> (u', v', block_max [R/BR*8, 128]): each
+    block's max|v'| fills one (8,128) tile of the last output."""
     R = u.shape[0]
     nb = R // BLOCK_ROWS
     sig = jnp.full((1, 1), sigma, jnp.float32)
@@ -54,11 +58,11 @@ def update_max(u, v, g, sigma, *, interpret=True):
         _update_max_kernel,
         grid=_grid(R),
         in_specs=[pl.BlockSpec((1, 1), lambda i: (0, 0)), blk, blk, blk],
-        out_specs=[blk, blk, pl.BlockSpec((1, 1), lambda i: (i, 0))],
+        out_specs=[blk, blk, pl.BlockSpec((SUBLANES, LANES), lambda i: (i, 0))],
         out_shape=[
             jax.ShapeDtypeStruct(u.shape, jnp.float32),
             jax.ShapeDtypeStruct(v.shape, jnp.float32),
-            jax.ShapeDtypeStruct((nb, 1), jnp.float32),
+            jax.ShapeDtypeStruct((nb * SUBLANES, LANES), jnp.float32),
         ],
         interpret=interpret,
     )(sig, u, v, g)
@@ -74,31 +78,34 @@ def _hist_kernel(edges_ref, v_ref, counts_ref, *, bins):
 
     @pl.when(i == 0)
     def _init():
-        counts_ref[...] = jnp.zeros_like(counts_ref)
+        for b in range(bins):
+            counts_ref[b] = 0
 
     a = jnp.abs(v_ref[...])  # [BR, BC]
-    edges = edges_ref[0, :]  # [bins]
-    # tail counts for every edge: [bins]
-    c = jnp.sum(
-        (a[None, :, :] >= edges[:, None, None]).astype(jnp.float32), axis=(1, 2)
-    )
-    counts_ref[0, :] += c
+
+    # one edge at a time: a [bins, BR, BC] broadcast would not fit VMEM
+    def count_edge(b, carry):
+        counts_ref[b] += jnp.sum((a >= edges_ref[b]).astype(jnp.int32))
+        return carry
+
+    jax.lax.fori_loop(0, bins, count_edge, 0)
 
 
-def tail_hist(v, edges, *, interpret=True):
-    """v [R, BLOCK_COLS]; edges [bins] -> counts [bins] (float32)."""
+def tail_hist(v, edges, *, interpret):
+    """v [R, BLOCK_COLS]; edges [bins] -> counts [bins] (int32), accumulated
+    in SMEM across the sequential grid."""
     R = v.shape[0]
     bins = edges.shape[0]
     blk = pl.BlockSpec((BLOCK_ROWS, BLOCK_COLS), lambda i: (i, 0))
-    counts = pl.pallas_call(
+    smem = pl.BlockSpec(memory_space=pltpu.SMEM)
+    return pl.pallas_call(
         functools.partial(_hist_kernel, bins=bins),
         grid=_grid(R),
-        in_specs=[pl.BlockSpec((1, bins), lambda i: (0, 0)), blk],
-        out_specs=pl.BlockSpec((1, bins), lambda i: (0, 0)),
-        out_shape=jax.ShapeDtypeStruct((1, bins), jnp.float32),
+        in_specs=[smem, blk],
+        out_specs=smem,
+        out_shape=jax.ShapeDtypeStruct((bins,), jnp.int32),
         interpret=interpret,
-    )(edges[None, :], v)
-    return counts[0]
+    )(edges.astype(jnp.float32), v)
 
 
 # ---------------------------------------------------------------------------
@@ -116,7 +123,7 @@ def _apply_kernel(th_ref, u_ref, v_ref, ghat_out, u_out, v_out):
     v_out[...] = v * keep
 
 
-def apply_mask(u, v, th, *, interpret=True):
+def apply_mask(u, v, th, *, interpret):
     """-> (ghat, u'', v'') all [R, BLOCK_COLS] f32."""
     R = u.shape[0]
     thr = jnp.asarray(th, jnp.float32).reshape(1, 1)

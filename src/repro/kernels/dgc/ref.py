@@ -12,9 +12,7 @@ def update_max_ref(u, v, g, sigma):
 
 def tail_hist_ref(v, edges):
     a = jnp.abs(v).reshape(-1)
-    return jnp.sum(
-        (a[None, :] >= edges[:, None]).astype(jnp.float32), axis=1
-    )
+    return jnp.sum((a[None, :] >= edges[:, None]).astype(jnp.int32), axis=1)
 
 
 def pick_threshold(counts, edges, k):

@@ -1,26 +1,22 @@
-"""Jit'd wrappers around the fused-sync kernel: exact whole-vector top-k
-without a whole-vector TopK sort.
+"""Exact whole-vector top-k without a whole-vector TopK sort.
 
 The dataflow is DGC's threshold select (``kernels/dgc``), finished to
 EXACT top-k semantics:
 
   1. *threshold estimate* — tail counts of ``|x|`` on a strided sample
-     against 64 linear edges (the jnp twin of the dgc ``tail_hist``
+     against linear edges (the jnp twin of the dgc ``tail_hist``
      kernel; same bin/pick semantics as ``dgc.ref.pick_threshold``),
      stepped down ``margin`` bins so sampling noise keeps the candidate
      count >= k.
   2. *mask + compact* — one pass emitting the candidates ``|x| >= th`` as
-     (values, indices) in index order. Compiled path: the Pallas
-     ``kernel.block_select`` (per-block fixed-capacity compaction, one
-     HBM pass). Interpret/CPU fallback: cumsum + searchsorted — the same
-     dataflow lowered to vectorizable XLA ops, mirroring the
-     interpret-mode switches of ``kernels/dgc`` and ``kernels/bitpack``.
-  3. *exact-k finisher* — a SMALL top-k over the ~1.3k candidates picks
+     (values, indices) in index order: cumsum + searchsorted, the same
+     XLA ops on every platform.
+  3. *exact-k finisher* — a SMALL top-k over the candidates picks
      the k winners. Candidates are emitted in index order and pad slots
      hold (0, n), so stable top-k tie-breaking matches whole-vector
      ``lax.top_k`` exactly: the returned indices are BIT-IDENTICAL to the
-     ``topk`` impl, at a fraction of its cost (the expensive sort shrinks
-     from Q to ~1.3k entries).
+     ``topk`` impl, while the expensive sort shrinks from Q entries to
+     the candidate buffer.
   4. *guaranteed-exact fallback* — if the threshold kept fewer than k or
      more than the candidate capacity (all-zero vectors, fewer-than-k
      nonzeros, adversarial ties), a ``lax.cond`` switches the whole batch
@@ -39,12 +35,14 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.kernels.fused_sync import kernel as K
-
 _TINY = np.float32(np.finfo(np.float32).tiny)
 _BINS = 128  # linear edges; drift |x| mass concentrates low, so fine bins
 _SAMPLE = 16384  # threshold-estimation sample size per row
 _MARGIN = 2  # extra bins of threshold slack against sampling noise
+# the TPU compiler takes minutes over a [R, n] cumsum whose n has a small
+# odd factor, and twice the memory when n is no multiple of 2^18: the
+# compaction pads its rank scan to this length
+_SCAN_ALIGN = 1 << 18
 
 
 def candidate_capacity(n: int, k: int) -> int:
@@ -83,17 +81,19 @@ def _row_threshold(A, k: int, *, bins: int, sample: int, margin: int):
     return jnp.maximum(th, _TINY)
 
 
-def _compact_jnp(S, th, cap: int):
-    """Interpret/CPU compaction: candidates of each row in index order.
+def _compact(S, th, cap: int):
+    """Candidates of each row in index order: (vals [R, cap], idx [R, cap]
+    with ``n`` as the pad slot, true counts m [R]).
 
-    cumsum ranks + one vectorized searchsorted per row — O(Q) passes that
-    XLA-CPU vectorizes, where a scatter of Q targets would serialize.
+    cumsum ranks + one vectorized searchsorted per row — O(Q) passes of
+    plain XLA ops, where a scatter of Q targets would serialize.
     """
     R, n = S.shape
     A = jnp.abs(S)
     mask = A >= th[:, None]
     # f32 ranks are exact below 2^24 and measurably faster on CPU
     cdt = jnp.float32 if n < (1 << 24) else jnp.int32
+    mask = jnp.pad(mask, ((0, 0), (0, -n % _SCAN_ALIGN)))
     c = jnp.cumsum(mask.astype(cdt), axis=1)
     m = c[:, -1].astype(jnp.int32)  # true candidate counts [R]
     if cdt == jnp.float32:
@@ -105,32 +105,7 @@ def _compact_jnp(S, th, cap: int):
     valid = jnp.arange(cap)[None, :] < m[:, None]
     vals = jnp.where(valid, jnp.take_along_axis(S, idx, axis=1), 0.0)
     idx = jnp.where(valid, idx, n)
-    overflow = jnp.zeros((R,), bool)  # jnp path never truncates below cap
-    return vals, idx, m, overflow
-
-
-def _compact_kernel(S, th, cap: int):
-    """Compiled compaction via the Pallas ``block_select`` kernel: fixed
-    per-block candidate slots, no cross-block offsets (pad slots lose to
-    every real candidate in the finisher)."""
-    R, n = S.shape
-    nb = -(-n // K.BLOCK_ELEMS)
-    cap_blk = min(K.BLOCK_ELEMS, -(-cap // nb) + (-(-cap // nb)) // 4 + 64)
-    pad = nb * K.BLOCK_ELEMS - n
-    vals_l, idx_l, m_l, of_l = [], [], [], []
-    for r in range(R):  # R is small and static (N clusters or 1)
-        xt = jnp.pad(S[r], (0, pad)).reshape(-1, K.BLOCK_COLS)
-        v, i, c = K.block_select(xt, th[r], cap_blk, n, interpret=False)
-        vals_l.append(v.reshape(-1))
-        idx_l.append(i.reshape(-1))
-        m_l.append(jnp.sum(c))
-        of_l.append(jnp.any(c[:, 0] > cap_blk))
-    return (
-        jnp.stack(vals_l),
-        jnp.stack(idx_l),
-        jnp.stack(m_l).astype(jnp.int32),
-        jnp.stack(of_l),
-    )
+    return vals, idx, m
 
 
 def _finish_topk(vals_c, idx_c, k: int):
@@ -155,12 +130,6 @@ def _exact_sort_rows(S, k: int):
     return jnp.take_along_axis(S, order, axis=1), order.astype(jnp.int32)
 
 
-# below this keep fraction the threshold pipeline beats XLA TopK on CPU;
-# above it (tiny k) XLA's k-sensitive partial TopK is already optimal and
-# the interpret fallback uses it directly (one BATCHED call per hop group)
-_PIPELINE_MIN_FRAC = 1 / 24
-
-
 def select_topk_rows(
     S,
     k: int,
@@ -168,33 +137,24 @@ def select_topk_rows(
     bins: int = _BINS,
     sample: int = _SAMPLE,
     margin: int = _MARGIN,
-    interpret: bool = True,
 ):
     """Exact top-k of every row of ``S`` [R, n]: (vals [R, k], idx [R, k]).
 
     Bit-identical selection to per-row ``lax.top_k(|S|, k)`` (including
     tie-breaking and the all-zero/near-empty edge cases), computed by
-    fused threshold select + compaction + small-top-k finisher, with a
+    threshold select + compaction + small-top-k finisher, with a
     stable-sort fallback when the threshold misses the [k, capacity]
-    window. ``interpret=True`` (CPU) lowers the compaction to
-    cumsum/searchsorted when the keep fraction is fat enough to beat
-    XLA's partial TopK, and to one batched ``lax.top_k`` otherwise (the
-    regime split XLA-CPU TopK's k-sensitivity dictates — either way ONE
-    launch per hop group); ``interpret=False`` uses the Pallas kernel.
+    window.
     """
     R, n = S.shape
     S = S.astype(jnp.float32)
     if k >= n:
         return _exact_sort_rows(S, k)
-    if interpret and k < _PIPELINE_MIN_FRAC * n:
-        vals, idx = jax.lax.top_k(jnp.abs(S), k)
-        return jnp.take_along_axis(S, idx, axis=1), idx.astype(jnp.int32)
     cap = candidate_capacity(n, k)
     th = _row_threshold(jnp.abs(S), k, bins=bins, sample=sample, margin=margin)
-    compact = _compact_jnp if interpret else _compact_kernel
-    vals_c, idx_c, m, overflow = compact(S, th, cap)
+    vals_c, idx_c, m = _compact(S, th, cap)
     vals, idx = _finish_topk(vals_c, idx_c, k)
-    ok = jnp.all((m >= k) & (m <= cap) & ~overflow)
+    ok = jnp.all((m >= k) & (m <= cap))
     return jax.lax.cond(
         ok,
         lambda args: (args[1], args[2]),
@@ -203,7 +163,7 @@ def select_topk_rows(
     )
 
 
-def fused_pack_phi(x, phi: float, *, interpret: bool = True, **kw):
+def fused_pack_phi(x, phi: float, **kw):
     """Single-vector Ω payload via the fused path: (values [k], indices
     [k] int32), k = ``keep_count(n, phi)`` — the ``omega_impl="fused"``
     twin of ``sparsify.pack_phi``."""
@@ -211,7 +171,7 @@ def fused_pack_phi(x, phi: float, *, interpret: bool = True, **kw):
 
     flat = x.reshape(-1)
     k = keep_count(flat.size, phi)
-    vals, idx = select_topk_rows(flat[None, :], k, interpret=interpret, **kw)
+    vals, idx = select_topk_rows(flat[None, :], k, **kw)
     return vals[0], idx[0]
 
 
@@ -238,7 +198,6 @@ def shard_select_candidates(
     bins: int = _BINS,
     sample: int = _SAMPLE,
     margin: int = _MARGIN,
-    interpret: bool = True,
 ):
     """Per-shard stage-1 of the sharded whole-vector Ω.
 
@@ -255,8 +214,7 @@ def shard_select_candidates(
     th = _row_threshold(
         jnp.abs(S_loc), k_target, bins=bins, sample=sample, margin=margin
     )
-    compact = _compact_jnp if interpret else _compact_kernel
-    vals_c, idx_c, m, _overflow = compact(S_loc, th, cap_s)
+    vals_c, idx_c, m = _compact(S_loc, th, cap_s)
     return vals_c, idx_c, m, th
 
 

@@ -21,8 +21,8 @@ Observability (``repro.obs``): ``--trace-viz out.json`` exports a
 Chrome/Perfetto trace of every simulator event on the virtual clock plus
 host-clock jit-boundary spans; ``--metrics-out run.jsonl`` streams every
 console line as a structured JSONL event and appends the final metrics-
-registry snapshot; ``--obs-hlo-cost`` adds compile-time HLO flop/byte/launch
-analysis of the jitted steps; ``--obs-health`` turns on the learning-health
+registry snapshot; ``--obs-hlo-cost`` adds HLO flop/byte/launch and
+device-memory analysis of the compiled steps; ``--obs-health`` turns on the learning-health
 monitor (per-cluster drift/residual/Ω-overlap from the jitted sync,
 staleness + participation fairness from the simulator, streaming anomaly
 rules -> JSONL ``health`` events + Perfetto counter tracks). Reporting also
@@ -34,6 +34,7 @@ from __future__ import annotations
 import argparse
 import json
 import time
+from typing import Any, NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
@@ -55,6 +56,7 @@ from repro.models.frontends import fake_frontend_embeds
 from repro.models.transformer import forward, init_model
 from repro.obs import ObsConfig, RunLogger, StepClock, make_telemetry
 from repro.optim import SGDM, warmup_step_decay
+from repro.utils.compile_cache import use_compile_cache
 
 
 def _jsonable(obj):
@@ -68,7 +70,20 @@ def _jsonable(obj):
     return obj
 
 
+class TrainResult(NamedTuple):
+    """What :func:`train` hands back: per-step mean losses, the held-out
+    eval loss of the consensus model, the final ``HFLState`` and the
+    ``StepClock`` summary."""
+
+    hist: list
+    eval_loss: float
+    state: Any
+    timing: dict
+
+
 def main(argv=None):
+    """Parse the command line, resolve the configs and run :func:`train`.
+    Returns ``(per-step losses, eval loss)``."""
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="olmo-1b")
     ap.add_argument("--reduced", action="store_true", default=True)
@@ -164,7 +179,8 @@ def main(argv=None):
     ap.add_argument("--obs-hlo-cost", action="store_true",
                     help="analyze the jitted train/sync steps' HLO "
                          "(flops, HBM bytes, collective bytes, launch "
-                         "count) at startup; costs one extra compile")
+                         "count, device memory) after the run, from the "
+                         "programs the run compiled")
     ap.add_argument("--obs-health", action="store_true",
                     help="learning-health monitor: per-cluster consensus "
                          "drift / residual norms / Ω overlap from the "
@@ -235,6 +251,30 @@ def main(argv=None):
     if scenario is not None:
         from repro.sim.scenarios import apply_hfl_overrides
         hfl = apply_hfl_overrides(scenario, hfl)
+    res = train(cfg, hfl, steps=args.steps, batch_per_mu=args.batch_per_mu,
+                seq=args.seq, lr=args.lr, log_every=args.log_every,
+                ckpt_dir=args.ckpt_dir, scenario=scenario,
+                sim_seed=args.sim_seed, trace_in=args.trace_in,
+                residency=args.residency, trace_out=args.trace_out,
+                obs_cfg=obs_cfg, log=log)
+    log.close()
+    return res.hist, res.eval_loss
+
+
+def train(cfg, hfl, *, steps: int, batch_per_mu: int = 8, seq: int = 64,
+          lr: float = 0.25, log_every: int = 20,
+          ckpt_dir: Optional[str] = None, scenario=None, sim_seed: int = 0,
+          trace_in: Optional[str] = None, residency: Optional[str] = None,
+          trace_out: Optional[str] = None, obs_cfg=None,
+          log: Optional[RunLogger] = None) -> TrainResult:
+    """Train ``cfg`` with the HFL engine under ``hfl`` on synthetic LM data.
+
+    ``hfl_init``, the jitted cluster train step (state donated), the
+    donated sync step from ``make_sync``, then ``run_hfl`` (or the
+    simulator engine when ``scenario`` is given), and the held-out eval
+    of the consensus model. Data and weights come from fixed seeds.
+    """
+    log = log if log is not None else RunLogger()
     log.log(
         "config",
         f"[train] arch={cfg.name} clusters={hfl.num_clusters} "
@@ -246,7 +286,7 @@ def main(argv=None):
         sync=hfl.sync_mode, layout=hfl.sync_layout, omega=hfl.omega_impl,
         payload_accounting=hfl.payload_accounting,
         scenario=(scenario.name if scenario is not None else None),
-        steps=args.steps, seq=args.seq, batch_per_mu=args.batch_per_mu,
+        steps=steps, seq=seq, batch_per_mu=batch_per_mu,
     )
 
     # the telemetry handle is created BEFORE the step builders run so their
@@ -255,9 +295,9 @@ def main(argv=None):
     engine = None
     if scenario is not None:
         from repro.sim.scenarios import build_engine
-        engine = build_engine(scenario, hfl, seed=args.sim_seed,
-                              trace_file=args.trace_in,
-                              residency=args.residency, obs=obs_cfg)
+        engine = build_engine(scenario, hfl, seed=sim_seed,
+                              trace_file=trace_in, residency=residency,
+                              obs=obs_cfg)
         tele = engine.obs
     else:
         tele = make_telemetry(obs_cfg)
@@ -265,56 +305,44 @@ def main(argv=None):
         # anomalies stream to the JSONL runlog as structured health events
         tele.health.runlog = log
 
-    params = init_model(jax.random.PRNGKey(0), cfg)
     opt = SGDM(momentum=0.9, weight_decay=1e-4)
-    sched = warmup_step_decay(args.lr * hfl.total_mus * args.batch_per_mu / 128,
-                              warmup_steps=max(args.steps // 20, 1),
-                              decay_steps=(args.steps // 2, 3 * args.steps // 4))
-    state = hfl_init(params, opt, hfl)
+    sched = warmup_step_decay(lr * hfl.total_mus * batch_per_mu / 128,
+                              warmup_steps=max(steps // 20, 1),
+                              decay_steps=(steps // 2, 3 * steps // 4))
+    # the initial model is not kept beside the state: the state holds N
+    # copies of it, and the donated steps need the device memory
+    state = hfl_init(init_model(jax.random.PRNGKey(0), cfg), opt, hfl)
 
     loss_fn = make_loss_fn(cfg)
-    train_step = jax.jit(make_cluster_train_step(loss_fn, opt, sched))
-    # sync consumes-and-replaces the whole state: donate it (peak-mem lever)
+    # both steps consume-and-replace the whole state: donate it, so its
+    # buffers are reused for the outputs instead of living twice
+    train_step = jax.jit(make_cluster_train_step(loss_fn, opt, sched),
+                         donate_argnums=0)
     # with --obs-health on a scenario run the sync also returns its in-jit
     # health statistics (supported on the local flat/fused/dense paths;
     # sharded layouts raise in make_sync_step, so gate on the flags)
     # in-sync health stats are a depth-2 local-flat feature; deeper
     # hierarchies run the tiered cascade which rejects collect_stats
-    collect = bool(args.obs_health and scenario is not None
-                   and args.sync_layout == "flat" and args.flat_shards == 1
+    collect = bool(tele.health.enabled and scenario is not None
+                   and hfl.sync_layout == "flat" and hfl.flat_shards == 1
                    and hfl.depth == 2)
     sync_step = jit_sync_step(
         make_sync(SyncPlan.from_config(hfl, collect_stats=collect)))
 
     lm = SyntheticLM(cfg.vocab_size, seed=1)
     rng = np.random.default_rng(2)
-    local_b = hfl.mus_per_cluster * args.batch_per_mu
+    local_b = hfl.mus_per_cluster * batch_per_mu
     F = cfg.frontend_tokens if cfg.frontend != "none" else 0
 
     def make_batches(lm_, rng_):
         while True:
-            toks = lm_.sample(hfl.num_clusters * local_b, args.seq, rng_)
-            b = {"tokens": jnp.asarray(toks.reshape(hfl.num_clusters, local_b, args.seq))}
+            toks = lm_.sample(hfl.num_clusters * local_b, seq, rng_)
+            b = {"tokens": jnp.asarray(toks.reshape(hfl.num_clusters, local_b, seq))}
             if F:
                 fe = fake_frontend_embeds(jax.random.PRNGKey(int(rng_.integers(1 << 30))),
                                           cfg, hfl.num_clusters * local_b)
                 b["frontend"] = fe.reshape(hfl.num_clusters, local_b, *fe.shape[1:])
             yield b
-
-    if obs_cfg is not None and obs_cfg.hlo_cost:
-        from repro.obs import program_costs
-        # probe batch from an INDEPENDENT generator with the same seeds:
-        # the training data stream must not be perturbed by profiling
-        probe = next(make_batches(SyntheticLM(cfg.vocab_size, seed=1),
-                                  np.random.default_rng(2)))
-        costs = {"train_step": program_costs(train_step, state, probe),
-                 "sync_step": program_costs(sync_step, state)}
-        for k, c in costs.items():
-            if c:
-                log.log("hlo_cost",
-                        f"[obs] {k}: {c['flops']/1e9:.3f} GFLOP "
-                        f"{c['hbm_bytes']/1e6:.1f} MB HBM "
-                        f"{c.get('launches', 0)} launches", fn=k, **c)
 
     hist = []
     clock = StepClock()
@@ -323,7 +351,7 @@ def main(argv=None):
         l = float(loss.mean())  # blocks until the step actually finished
         clock.step()
         hist.append(l)
-        if (t + 1) % args.log_every == 0:
+        if (t + 1) % log_every == 0:
             ss = clock.steady_s_per_step
             # steady rate once a post-compile sample exists; the first
             # window falls back to the compile-inclusive mean
@@ -343,7 +371,7 @@ def main(argv=None):
             donate_argnums=0)
         state, trace = engine.run(state, train_step, sync_step,
                                   make_batches(lm, rng),
-                                  args.steps, on_step=on_step,
+                                  steps, on_step=on_step,
                                   masked_train_step=masked_step)
         m = trace.meta
         log.log("sim_summary",
@@ -367,20 +395,43 @@ def main(argv=None):
                     f"t_hfl_iter={m['t_hfl_iter_s']:.3f}s "
                     f"t_hfl_period={m['t_hfl_period_s']:.3f}s "
                     f"(period<fl_iter: {m['t_hfl_period_s'] < m['t_fl_iter_s']})")
-        if args.trace_out:
-            with open(args.trace_out, "w") as f:
+        if trace_out:
+            with open(trace_out, "w") as f:
                 json.dump(_jsonable(trace.to_json()), f, indent=1)
-            log.log("trace_out", f"[sim] trace -> {args.trace_out}",
-                    path=args.trace_out)
-        if args.trace_viz and tele.enabled:
-            tele.export_chrome(args.trace_viz,
+            log.log("trace_out", f"[sim] trace -> {trace_out}",
+                    path=trace_out)
+        trace_viz = obs_cfg.trace_path if obs_cfg is not None else None
+        if trace_viz and tele.enabled:
+            tele.export_chrome(trace_viz,
                                metadata={"engine_meta": _jsonable(m)})
-            log.log("trace_viz", f"[obs] chrome trace -> {args.trace_viz}",
-                    path=args.trace_viz, events=len(tele.tracer.events),
+            log.log("trace_viz", f"[obs] chrome trace -> {trace_viz}",
+                    path=trace_viz, events=len(tele.tracer.events),
                     dropped=tele.tracer.dropped)
     else:
         state = run_hfl(state, train_step, sync_step, make_batches(lm, rng),
-                        hfl.tiers[1].period, args.steps, on_step)
+                        hfl.tiers[1].period, steps, on_step)
+
+    costs = {}
+    if obs_cfg is not None and obs_cfg.hlo_cost:
+        from repro.obs import program_costs
+        # after the run: lowering the steps again finds the programs the
+        # run compiled, so the analysis costs no extra compile. The probe
+        # batch comes from an independent generator with the data seeds.
+        probe = next(make_batches(SyntheticLM(cfg.vocab_size, seed=1),
+                                  np.random.default_rng(2)))
+        costs["train_step"] = program_costs(train_step, state, probe)
+        if not getattr(sync_step, "hier", False):
+            # a depth > 2 sync is one program per tier boundary
+            costs["sync_step"] = program_costs(sync_step, state)
+        for k, c in costs.items():
+            log.log("hlo_cost",
+                    f"[obs] {k}: {c['flops']/1e9:.3f} GFLOP "
+                    f"{c['hbm_bytes']/1e6:.1f} MB HBM "
+                    f"{c['launches']} launches; device memory "
+                    f"args={c['argument_bytes']/1e9:.3f} GB "
+                    f"out={c['output_bytes']/1e9:.3f} GB "
+                    f"alias={c['alias_bytes']/1e9:.3f} GB "
+                    f"temp={c['temp_bytes']/1e9:.3f} GB", fn=k, **c)
 
     timing = clock.summary()
     if timing["steps"]:
@@ -391,13 +442,20 @@ def main(argv=None):
                    else "  (one step; no steady-state sample)"),
                 **timing)
 
-    # held-out eval with the consensus model
+    # held-out eval with the consensus model, 8 sequences at a time: the
+    # f32 log-probs of all 32 at a full vocabulary would not fit beside
+    # the state on one chip
     sp = serving_params(state)
-    toks = jnp.asarray(lm.sample(32, args.seq, np.random.default_rng(99)))
+    toks = jnp.asarray(lm.sample(32, seq, np.random.default_rng(99)))
     fe = fake_frontend_embeds(jax.random.PRNGKey(7), cfg, 32) if F else None
-    logits, _ = forward(sp, toks, cfg, frontend_embeds=fe)
-    lp = jax.nn.log_softmax(logits[:, -args.seq:].astype(jnp.float32), -1)
-    eval_loss = float(-jnp.take_along_axis(lp[:, :-1], toks[:, 1:, None], -1).mean())
+    nll = []
+    for i in range(0, 32, 8):
+        tk = toks[i:i + 8]
+        logits, _ = forward(sp, tk, cfg,
+                            frontend_embeds=None if fe is None else fe[i:i + 8])
+        lp = jax.nn.log_softmax(logits[:, -seq:].astype(jnp.float32), -1)
+        nll.append(-jnp.take_along_axis(lp[:, :-1], tk[:, 1:, None], -1).mean())
+    eval_loss = float(jnp.mean(jnp.stack(nll)))
     if hist:  # async with steps < H completes zero rounds -> no train losses
         log.log("eval",
                 f"[train] first-loss={hist[0]:.4f} last-loss={hist[-1]:.4f} "
@@ -408,8 +466,8 @@ def main(argv=None):
                 f"[train] no training rounds completed; "
                 f"eval-loss={eval_loss:.4f}", eval_loss=eval_loss)
 
-    if args.ckpt_dir:
-        path = save_checkpoint(args.ckpt_dir, args.steps, state._asdict())
+    if ckpt_dir:
+        path = save_checkpoint(ckpt_dir, steps, state._asdict())
         log.log("checkpoint", f"[train] checkpoint -> {path}", path=str(path))
     if tele.health.enabled:
         hs = tele.health.summary()
@@ -431,11 +489,14 @@ def main(argv=None):
                       f"p50={s['p50']:.4g} p95={s['p95']:.4g} "
                       f"p99={s['p99']:.4g} max={s['max']:.4g}")
         log.log("metrics", None, metrics=snap)
-    log.close()
-    # one return shape for every mode; the wall-clock trace is exposed via
-    # --trace-out (scenario runs) rather than a third tuple element
-    return hist, eval_loss
+    return TrainResult(hist, eval_loss, state, timing)
+
+
+def cli() -> None:
+    """Console entry: the persistent compile cache, then :func:`main`."""
+    use_compile_cache()
+    main()
 
 
 if __name__ == "__main__":
-    main()
+    cli()

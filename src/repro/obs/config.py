@@ -36,8 +36,8 @@ class ObsConfig:
     # emit a live events/s + live-bytes heartbeat every N engine events
     # (gauges in the registry + one stderr line); 0 = off
     heartbeat_events: int = 0
-    # lower/compile the train step once and record flops/bytes/launch
-    # counts via launch/hlo_cost (one extra compile — opt-in)
+    # after the run, analyze the compiled train/sync steps: flops/bytes/
+    # launch counts via launch/hlo_cost, and their device memory
     hlo_cost: bool = False
     # span-event cap: fleet-scale runs keep the trace bounded. Past the
     # cap events are counted (``dropped_events`` in the export metadata)

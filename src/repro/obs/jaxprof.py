@@ -7,11 +7,11 @@ Three small tools, all host-side and backend-agnostic:
     total elapsed by step count, silently folding the compile stall into
     every step; ``compile_s`` and ``steady_s_per_step`` report the two
     separately.
-  * ``program_costs`` — lowers/compiles a jitted callable once and runs
-    the trip-count-aware ``launch/hlo_cost`` analysis over the HLO text:
-    flops, HBM bytes, collective bytes, plus a top-level launch count
-    (entry instructions that actually dispatch work). One extra compile —
-    opt-in via ``ObsConfig.hlo_cost``.
+  * ``program_costs`` — lowers/compiles a jitted callable and runs the
+    trip-count-aware ``launch/hlo_cost`` analysis over the HLO text:
+    flops, HBM bytes, collective bytes, a top-level launch count (entry
+    instructions that actually dispatch work) and the program's device
+    memory. Opt-in via ``ObsConfig.hlo_cost``.
   * ``live_bytes`` — current live device-array footprint (the heartbeat's
     peak-memory proxy; works on CPU where ``memory_stats`` is absent).
 """
@@ -67,50 +67,39 @@ class StepClock:
 
 def program_costs(fn, *args, **kwargs) -> dict:
     """Lower + compile ``fn(*args)`` and analyze the HLO: trip-count-aware
-    flops/bytes/collective bytes (``launch/hlo_cost``) plus the top-level
-    launch count. Returns ``{}`` when the backend/jax version exposes no
-    compiled text (the hooks degrade, they never fail a run)."""
-    try:
-        compiled = fn.lower(*args, **kwargs).compile()
-        txt = compiled.as_text()
-    except Exception:
-        return {}
+    flops/bytes/collective bytes (``launch/hlo_cost``), the top-level
+    launch count, and the compiled program's device memory
+    (``memory_analysis()``: argument, output, aliased and temporary
+    bytes). A jitted ``fn`` already called with these arguments is not
+    compiled again."""
     from repro.launch.hlo_cost import HloCost
 
-    try:
-        hc = HloCost(txt)
-        cost = hc.entry_cost()
-        entry = hc.entry
-        launches = None
-        if entry is not None and entry in hc.comps:
-            launches = sum(1 for ins in hc.comps[entry]
-                           if ins.op not in _NO_LAUNCH_OPS)
-        out = {"flops": cost["flops"], "hbm_bytes": cost["bytes"],
-               "collective_bytes": float(sum(cost["coll"].values()))}
-        if launches is not None:
-            out["launches"] = launches
-        return out
-    except Exception:
-        return {}
+    compiled = fn.lower(*args, **kwargs).compile()
+    hc = HloCost(compiled.as_text())
+    cost = hc.entry_cost()
+    launches = sum(1 for ins in hc.comps[hc.entry]
+                   if ins.op not in _NO_LAUNCH_OPS)
+    mem = compiled.memory_analysis()
+    return {"flops": cost["flops"], "hbm_bytes": cost["bytes"],
+            "collective_bytes": float(sum(cost["coll"].values())),
+            "launches": launches,
+            "argument_bytes": mem.argument_size_in_bytes,
+            "output_bytes": mem.output_size_in_bytes,
+            "alias_bytes": mem.alias_size_in_bytes,
+            "temp_bytes": mem.temp_size_in_bytes}
 
 
 def live_bytes() -> float:
     """Bytes of live device arrays (CPU-safe peak-memory proxy)."""
-    try:
-        import jax
+    import jax
 
-        return float(sum(getattr(a, "nbytes", 0) for a in jax.live_arrays()))
-    except Exception:
-        return 0.0
+    return float(sum(a.nbytes for a in jax.live_arrays()))
 
 
 def device_memory_stats() -> dict:
-    """Best-effort ``device.memory_stats()`` of the default device
-    (empty on backends that expose none, e.g. CPU)."""
-    try:
-        import jax
+    """``memory_stats()`` of the default device; empty on a backend that
+    keeps none (the CPU returns ``None``)."""
+    import jax
 
-        stats = jax.devices()[0].memory_stats()
-        return dict(stats) if stats else {}
-    except Exception:
-        return {}
+    stats = jax.devices()[0].memory_stats()
+    return dict(stats) if stats else {}

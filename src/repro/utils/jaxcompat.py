@@ -1,18 +1,13 @@
-"""Version-compatibility shims for jax APIs that moved between releases.
+"""The one home of the jax APIs that have moved between releases.
 
-The repo targets current jax but must run on the pinned container image
-(jax 0.4.x). Three APIs drifted:
+Written against jax 0.9 (``requirements-dev.txt``). Three APIs changed shape
+in the releases before it and may move again, so every caller goes through
+these wrappers (``tools/lint_jaxcompat.py`` enforces it) and a later
+migration is an edit of this file alone:
 
-  * ``shard_map``     : ``jax.shard_map(..., check_vma=...)`` vs
-                        ``jax.experimental.shard_map.shard_map(..., check_rep=...)``
-  * ``make_mesh``     : the ``axis_types=`` kwarg (and ``jax.sharding.AxisType``)
-                        does not exist on 0.4.x; its newer default (Auto) is
-                        exactly the old behaviour.
-  * ``cost_analysis`` : ``Compiled.cost_analysis()`` returns a per-device
-                        ``list[dict]`` on 0.4.x and a plain ``dict`` later.
-
-Everything else in the repo goes through these three wrappers instead of
-version-sniffing locally.
+  * ``shard_map``     : ``jax.shard_map(..., check_vma=False)``
+  * ``make_mesh``     : ``jax.make_mesh`` with explicit ``AxisType.Auto`` axes
+  * ``cost_analysis`` : ``Compiled.cost_analysis()``, a flat ``dict``
 """
 from __future__ import annotations
 
@@ -22,34 +17,16 @@ import jax
 def shard_map(f, *, mesh, in_specs, out_specs):
     """Fully-manual shard_map with replication checking off (our sync
     functions are deliberately non-replicated over "pod")."""
-    if hasattr(jax, "shard_map"):
-        try:
-            return jax.shard_map(
-                f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_vma=False
-            )
-        except TypeError:  # very new jax renamed/dropped the kwarg again
-            return jax.shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs)
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    return _shard_map(
-        f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_rep=False
-    )
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
+                         check_vma=False)
 
 
 def make_mesh(shape, axes):
-    """jax.make_mesh with explicit Auto axis types where supported."""
-    try:
-        return jax.make_mesh(
-            tuple(shape), tuple(axes),
-            axis_types=(jax.sharding.AxisType.Auto,) * len(axes),
-        )
-    except (AttributeError, TypeError):
-        return jax.make_mesh(tuple(shape), tuple(axes))
+    """jax.make_mesh with explicit Auto axis types."""
+    return jax.make_mesh(tuple(shape), tuple(axes),
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def cost_analysis_dict(compiled) -> dict:
-    """Normalise Compiled.cost_analysis() to a single flat dict."""
-    ca = compiled.cost_analysis()
-    if isinstance(ca, (list, tuple)):
-        ca = ca[0] if ca else {}
-    return dict(ca)
+    """Compiled.cost_analysis() as a plain dict."""
+    return dict(compiled.cost_analysis())

@@ -78,7 +78,7 @@ def test_dense_roundtrip(name):
 
 
 # ---------------------------------------------------------------------------
-# Property tests (skip gracefully without hypothesis, like the other suites)
+# Property tests
 # ---------------------------------------------------------------------------
 
 
@@ -87,8 +87,10 @@ def payloads(draw):
     size = draw(st.integers(1, 300))
     k = draw(st.integers(1, size))
     idx = draw(st.sets(st.integers(0, size - 1), min_size=k, max_size=k))
+    # hypothesis wants width-32 bounds that are exact float32 values
+    bound = float(np.float32(1e20))
     vals = draw(st.lists(
-        st.floats(-1e20, 1e20, allow_nan=False, allow_infinity=False,
+        st.floats(-bound, bound, allow_nan=False, allow_infinity=False,
                   width=32),
         min_size=k, max_size=k,
     ))

@@ -1,6 +1,6 @@
-"""Fused top-k/compaction sync kernel: exact equivalence vs ``topk``
-(masks, payloads, whole syncs), the Pallas kernel vs its oracle, sharded
-stage-1 + merge, and the engine-facing routing (``omega_impl="fused"``)."""
+"""Fused top-k/compaction sync: exact equivalence vs ``topk`` (masks,
+payloads, whole syncs), sharded stage-1 + merge, and the engine-facing
+routing (``omega_impl="fused"``)."""
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -9,8 +9,7 @@ import pytest
 from repro.configs.base import HFLConfig, ModelConfig
 from repro.core import sparsify as sp
 from repro.core.hfl import hfl_init, jit_sync_step, make_sync_step
-from repro.kernels.fused_sync import kernel as K
-from repro.kernels.fused_sync import ops, ref
+from repro.kernels.fused_sync import ops
 from repro.models.transformer import init_model
 from repro.optim import SGDM
 
@@ -32,54 +31,6 @@ def _multi_leaf_state(hfl, seed=0):
         eps=jax.tree.map(lambda p: perturb(p, next(keys), 0.01), state.eps),
         e=jax.tree.map(lambda p: perturb(p, next(keys), 0.01), state.e),
     )
-
-
-# ---------------------------------------------------------------------------
-# Pallas kernel vs oracle
-# ---------------------------------------------------------------------------
-
-
-@pytest.mark.parametrize("n", [K.BLOCK_ELEMS, 3 * K.BLOCK_ELEMS - 777])
-def test_block_select_kernel_vs_ref(n):
-    x = jax.random.normal(jax.random.PRNGKey(n % 17), (n,))
-    pad = (-n) % K.BLOCK_ELEMS
-    xp = jnp.pad(x, (0, pad))
-    th = 1.5
-    cap_blk = 4096
-    v, i, c = K.block_select(
-        xp.reshape(-1, K.BLOCK_COLS), th, cap_blk, n, interpret=True)
-    vr, ir, cr = ref.block_select_ref(xp, th, cap_blk, K.BLOCK_ELEMS)
-    np.testing.assert_array_equal(np.asarray(v), np.asarray(vr))
-    np.testing.assert_array_equal(np.asarray(i), np.asarray(ir))
-    np.testing.assert_array_equal(np.asarray(c[:, 0]), np.asarray(cr))
-
-
-def test_block_select_kernel_truncates_at_capacity():
-    n = K.BLOCK_ELEMS
-    x = jnp.ones((n,))  # every entry is a candidate
-    cap_blk = 128
-    v, i, c = K.block_select(
-        x.reshape(-1, K.BLOCK_COLS), 0.5, cap_blk, n, interpret=True)
-    assert int(c[0, 0]) == n  # true count reported pre-truncation
-    np.testing.assert_array_equal(  # first cap_blk in index order kept
-        np.asarray(i[0]), np.arange(cap_blk, dtype=np.int32))
-
-
-def test_kernel_candidates_finish_to_exact_topk():
-    """The compiled-path dataflow (block_select candidates -> finisher)
-    must reproduce whole-vector top-k exactly."""
-    n = 2 * K.BLOCK_ELEMS
-    x = jax.random.normal(jax.random.PRNGKey(3), (n,))
-    k = n // 10
-    th = ops._row_threshold(jnp.abs(x)[None, :], k, bins=128,
-                            sample=16384, margin=2)[0]
-    cap_blk = K.BLOCK_ELEMS // 4
-    v, i, c = K.block_select(
-        x.reshape(-1, K.BLOCK_COLS), th, cap_blk, n, interpret=True)
-    assert int(jnp.max(c)) <= cap_blk  # no block overflow on this data
-    vals, idx = ops._finish_topk(v.reshape(1, -1), i.reshape(1, -1), k)
-    _, exact = jax.lax.top_k(jnp.abs(x), k)
-    np.testing.assert_array_equal(np.asarray(idx[0]), np.asarray(exact))
 
 
 # ---------------------------------------------------------------------------

@@ -49,7 +49,7 @@ def test_update_max_kernel_direct():
     u = jax.random.normal(jax.random.PRNGKey(3), (R, K.BLOCK_COLS))
     v = jax.random.normal(jax.random.PRNGKey(4), (R, K.BLOCK_COLS))
     g = jax.random.normal(jax.random.PRNGKey(5), (R, K.BLOCK_COLS))
-    u2, v2, bmax = K.update_max(u, v, g, 0.7)
+    u2, v2, bmax = K.update_max(u, v, g, 0.7, interpret=True)
     ur, vr, hi = ref.update_max_ref(u, v, g, 0.7)
     np.testing.assert_allclose(np.asarray(u2), np.asarray(ur), rtol=1e-5, atol=1e-5)
     np.testing.assert_allclose(np.asarray(v2), np.asarray(vr), rtol=1e-5, atol=1e-5)
@@ -60,6 +60,6 @@ def test_tail_hist_kernel_direct():
     R = K.BLOCK_ROWS * 3
     v = jax.random.normal(jax.random.PRNGKey(6), (R, K.BLOCK_COLS))
     edges = jnp.linspace(1e-30, float(jnp.abs(v).max()), 32)
-    counts = K.tail_hist(v, edges)
+    counts = K.tail_hist(v, edges, interpret=True)
     counts_r = ref.tail_hist_ref(v, edges)
     np.testing.assert_allclose(np.asarray(counts), np.asarray(counts_r))

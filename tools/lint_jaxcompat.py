@@ -1,14 +1,13 @@
 #!/usr/bin/env python
 """Lint: version-sensitive jax APIs must route through utils/jaxcompat.py.
 
-Three jax APIs drifted across the releases the repo supports (pinned 0.4.x
-container vs latest): ``shard_map`` (module + kwarg rename), ``make_mesh``
-(the ``axis_types=``/``AxisType`` kwarg), and ``Compiled.cost_analysis()``
-(per-device list vs flat dict). ``repro/utils/jaxcompat.py`` papers over
-all three; a direct call anywhere else reintroduces exactly the breakage
-the CI jax matrix exists to catch — but only on the leg that happens to
-disagree with the author's local version. This linter fails the build on
-ANY direct use, on both legs, before the drift can land.
+Three jax APIs changed shape across past releases: ``shard_map`` (module
++ kwarg rename), ``make_mesh`` (the ``axis_types=``/``AxisType`` kwarg),
+and ``Compiled.cost_analysis()`` (per-device list vs flat dict).
+``repro/utils/jaxcompat.py`` holds the one call of each, written against
+the installed jax (``requirements-dev.txt``), so the next move is an edit
+of that file alone. This linter fails the build on ANY direct use
+elsewhere, before a second call site can land.
 
 AST-based, so mentions in comments/docstrings (including this one) don't
 trip it. Exit 1 on findings.
@@ -105,8 +104,8 @@ def main(argv=None) -> int:
         print(f"{path}:{line}: version-sensitive jax API `{what}` — "
               f"use {fix} instead")
     if hits:
-        print(f"lint_jaxcompat: {len(hits)} finding(s); these APIs drift "
-              f"across the CI jax matrix — route them through "
+        print(f"lint_jaxcompat: {len(hits)} finding(s); these APIs move "
+              f"between jax releases — route them through "
               f"repro/utils/jaxcompat.py", file=sys.stderr)
         return 1
     print(f"lint_jaxcompat: ok ({len(files)} files clean)")
