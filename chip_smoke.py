@@ -57,7 +57,6 @@ from repro.launch.steps import (  # noqa: E402
 )
 from repro.launch.train import train  # noqa: E402
 from repro.models.transformer import init_model  # noqa: E402
-from repro.obs import ObsConfig  # noqa: E402
 from repro.obs.jaxprof import device_memory_stats  # noqa: E402
 from repro.optim import constant_lr  # noqa: E402
 from repro.utils.compile_cache import use_compile_cache  # noqa: E402
@@ -193,7 +192,7 @@ def one_chip() -> Checks:
     hfl = HFLConfig(tiers=parse_tiers_spec("2x2:H=2"), sync_mode="sparse",
                     omega_impl="topk", sync_layout="flat")
     res = train(cfg, hfl, steps=6, batch_per_mu=BATCH_PER_MU, seq=SEQ,
-                log_every=1, obs_cfg=ObsConfig(hlo_cost=True))
+                log_every=1)
     state = res.state
     print(f"[smoke] HFLState bytes={tree_bytes(state)} "
           f"(params, opt, eps x{hfl.num_clusters}; w_ref; e)")
@@ -206,7 +205,9 @@ def one_chip() -> Checks:
     check_kernels(check)
     state = check_syncs(check, state, hfl)
     mem = device_memory_stats()
-    print(f"[smoke] peak_bytes_in_use={mem.get('peak_bytes_in_use')} "
+    print(f"[smoke] peak_bytes={mem.get('peak_bytes')} (in use "
+          f"{mem.get('peak_bytes_in_use')} + reserved "
+          f"{mem.get('peak_bytes_reserved')}) "
           f"bytes_limit={mem.get('bytes_limit')}")
     return check
 

@@ -100,21 +100,32 @@ def serving_params(state: HFLState):
 # Intra-cluster train step
 # ---------------------------------------------------------------------------
 
+# The train step's ops sit under two named scopes, which change only the
+# HLO metadata and so the names in the profiler's trace: ``train.grad``
+# (forward, and under ``transpose(...)`` backward) and ``train.optimizer``.
+# ``models.attention`` adds ``attention`` inside both passes.
+
 
 def make_cluster_train_step(loss_fn: Callable, optimizer, lr_schedule):
     """loss_fn(params, batch) -> (loss, aux). batch leaves [N, localB, ...]."""
     _count_build("train_step", masked="no")
 
     def train_step(state: HFLState, batch):
-        lr = lr_schedule(state.step)
+        with jax.named_scope("train.optimizer"):
+            lr = lr_schedule(state.step)
 
         def one_cluster(params, opt, cbatch):
-            (loss, _aux), grads = jax.value_and_grad(loss_fn, has_aux=True)(params, cbatch)
-            new_params, new_opt = optimizer.update(grads, opt, params, lr)
+            with jax.named_scope("train.grad"):
+                (loss, _aux), grads = jax.value_and_grad(
+                    loss_fn, has_aux=True)(params, cbatch)
+            with jax.named_scope("train.optimizer"):
+                new_params, new_opt = optimizer.update(grads, opt, params, lr)
             return new_params, new_opt, loss
 
         params, opt, losses = jax.vmap(one_cluster)(state.params, state.opt, batch)
-        return state._replace(params=params, opt=opt, step=state.step + 1), losses
+        with jax.named_scope("train.optimizer"):
+            step = state.step + 1
+        return state._replace(params=params, opt=opt, step=step), losses
 
     return train_step
 
@@ -137,15 +148,21 @@ def make_masked_cluster_train_step(loss_fn: Callable, optimizer, lr_schedule):
     _count_build("train_step", masked="yes")
 
     def train_step(state: HFLState, batch_n, n):
-        lr = lr_schedule(state.step)
-        params_n = jax.tree.map(lambda p: p[n], state.params)
-        opt_n = jax.tree.map(lambda o: o[n], state.opt)
-        (loss, _aux), grads = jax.value_and_grad(loss_fn, has_aux=True)(
-            params_n, batch_n)
-        new_p, new_o = optimizer.update(grads, opt_n, params_n, lr)
-        params = jax.tree.map(lambda P, q: P.at[n].set(q), state.params, new_p)
-        opt = jax.tree.map(lambda O, q: O.at[n].set(q), state.opt, new_o)
-        return state._replace(params=params, opt=opt, step=state.step + 1), loss
+        # the row's slicing and write-back are the update's own bookkeeping
+        with jax.named_scope("train.optimizer"):
+            lr = lr_schedule(state.step)
+            params_n = jax.tree.map(lambda p: p[n], state.params)
+            opt_n = jax.tree.map(lambda o: o[n], state.opt)
+        with jax.named_scope("train.grad"):
+            (loss, _aux), grads = jax.value_and_grad(loss_fn, has_aux=True)(
+                params_n, batch_n)
+        with jax.named_scope("train.optimizer"):
+            new_p, new_o = optimizer.update(grads, opt_n, params_n, lr)
+            params = jax.tree.map(lambda P, q: P.at[n].set(q), state.params,
+                                  new_p)
+            opt = jax.tree.map(lambda O, q: O.at[n].set(q), state.opt, new_o)
+            step = state.step + 1
+        return state._replace(params=params, opt=opt, step=step), loss
 
     return train_step
 
@@ -178,17 +195,29 @@ def _wire_round_rows(x, fmt: str):
     """Row-batched wire rounding: each leading-axis row is one cluster's
     payload, so the q8 scale reduces over the LAST axis only —
     bit-identical to looping ``_wire_round`` over rows (the fused sync
-    batches the N uplink hops)."""
-    if fmt == "bf16":
-        return x.astype(jnp.bfloat16).astype(jnp.float32)
-    if fmt == "q8":
-        amax = jnp.max(jnp.abs(x), axis=-1, keepdims=True)
-        scale = jnp.where(amax > 0, amax / jnp.float32(127.0), jnp.float32(1.0))
-        return jnp.clip(jnp.round(x / scale), -127.0, 127.0) * scale
+    batches the N uplink hops). Wire rounding is part of ``sync.compact``."""
+    with jax.named_scope("sync.compact"):
+        if fmt == "bf16":
+            return x.astype(jnp.bfloat16).astype(jnp.float32)
+        if fmt == "q8":
+            amax = jnp.max(jnp.abs(x), axis=-1, keepdims=True)
+            scale = jnp.where(amax > 0, amax / jnp.float32(127.0),
+                              jnp.float32(1.0))
+            return jnp.clip(jnp.round(x / scale), -127.0, 127.0) * scale
     raise ValueError(fmt)
 
 
 # ---- flat layout: the paper's whole-model Ω, one launch per hop -----------
+#
+# Every op of a sync program sits under exactly one of four named scopes
+# (HLO metadata only; the profiler's trace carries them): ``sync.select``
+# builds the drift vectors and chooses Ω, ``sync.compact`` gathers the kept
+# values and indices into the payload (wire rounding included),
+# ``sync.exchange`` combines the payloads across clusters, and
+# ``sync.merge`` scatters back to dense, updates the error buffers and the
+# reference, and has the clusters adopt it. The helpers here and in
+# ``core.sparsify`` open their own scope; the builders scope only their
+# inline ops, so scopes never nest.
 
 
 def _flat_sync_stats(wn, new_eps, new_e, new_wref, d, ul_idx, dl_idx):
@@ -229,8 +258,9 @@ def _make_flat_local_sync(hfl_cfg, wire, collect_stats: bool = False):
 
     def flat_sync(state: HFLState):
         N = hfl_cfg.num_clusters
-        wref, ref_spec = fl.pack(state.w_ref)
-        e, _ = fl.pack(state.e)
+        with jax.named_scope("sync.select"):
+            wref, ref_spec = fl.pack(state.w_ref)
+            e, _ = fl.pack(state.e)
         p_spec = fl.spec_of_stacked(state.params)
         Q = ref_spec.total
 
@@ -239,32 +269,39 @@ def _make_flat_local_sync(hfl_cfg, wire, collect_stats: bool = False):
         s, eps_spec = _pack_drift(state, hfl_cfg.tiers[1].beta_up)  # [N, Q]
         sents, new_eps, ul_idx = [], [], []
         for n in range(N):  # static unroll; N is small
-            vals, idx = sp.pack_phi(s[n], hfl_cfg.tiers[1].phi_up, impl=impl)
+            with jax.named_scope("sync.select"):
+                s_n = s[n]
+            vals, idx = sp.pack_phi(s_n, hfl_cfg.tiers[1].phi_up, impl=impl)
             if wire:
                 vals = _wire_round(vals, wire)
             sent = sp.unpack_topk(vals, idx, Q)
             sents.append(sent)
-            new_eps.append(s[n] - sent)
+            with jax.named_scope("sync.merge"):
+                new_eps.append(s[n] - sent)
             ul_idx.append(idx)
 
         # --- MBS side: consensus + discounted error + top-k downlink ---
-        delta = sum(sents) / N + hfl_cfg.tiers[1].beta_down * e
+        with jax.named_scope("sync.exchange"):
+            mean = sum(sents) / N
+        with jax.named_scope("sync.select"):
+            delta = mean + hfl_cfg.tiers[1].beta_down * e
         dvals, didx = sp.pack_phi(delta, hfl_cfg.tiers[1].phi_down, impl=impl)
         if wire:
             dvals = _wire_round(dvals, wire)
         d = sp.unpack_topk(dvals, didx, Q)
-        new_e = delta - d
-        new_wref = wref + d
+        with jax.named_scope("sync.merge"):
+            new_e = delta - d
+            new_wref = wref + d
 
-        # --- clusters adopt the new reference (Alg.5 l.33/43) ---
-        new_wn = jnp.broadcast_to(new_wref[None], (N, Q))
-        eps_stacked = jnp.stack(new_eps)
-        new_state = state._replace(
-            params=fl.unpack_stacked(new_wn, p_spec),
-            w_ref=fl.unpack(new_wref, ref_spec),
-            eps=fl.unpack_stacked(eps_stacked, eps_spec),
-            e=fl.unpack(new_e, ref_spec),
-        )
+            # --- clusters adopt the new reference (Alg.5 l.33/43) ---
+            new_wn = jnp.broadcast_to(new_wref[None], (N, Q))
+            eps_stacked = jnp.stack(new_eps)
+            new_state = state._replace(
+                params=fl.unpack_stacked(new_wn, p_spec),
+                w_ref=fl.unpack(new_wref, ref_spec),
+                eps=fl.unpack_stacked(eps_stacked, eps_spec),
+                e=fl.unpack(new_e, ref_spec),
+            )
         if not collect_stats:
             return new_state
         wn, _ = fl.pack_stacked(state.params)
@@ -285,18 +322,21 @@ def _flat_shard_sync(params, w_ref, eps, e, *, hfl_cfg, wire):
     """
     impl = hfl_cfg.omega_impl
     N = hfl_cfg.num_clusters
-    wref, ref_spec = fl.pack(w_ref)
-    e_v, _ = fl.pack(e)
-    wn, p_spec = fl.pack_stacked(params)  # [C, Qloc]
-    eps_m, eps_spec = fl.pack_stacked(eps)
-    C = wn.shape[0]
-    Q = ref_spec.total
+    with jax.named_scope("sync.select"):
+        wref, ref_spec = fl.pack(w_ref)
+        e_v, _ = fl.pack(e)
+        wn, p_spec = fl.pack_stacked(params)  # [C, Qloc]
+        eps_m, eps_spec = fl.pack_stacked(eps)
+        C = wn.shape[0]
+        Q = ref_spec.total
 
-    # --- SBS side (Alg.5 l.24-27): one whole-vector Ω per hosted cluster ---
-    s = wn - wref[None, :] + hfl_cfg.tiers[1].beta_up * eps_m  # [C, Qloc]
+        # --- SBS side (Alg.5 l.24-27): one whole-vector Ω per cluster ---
+        s = wn - wref[None, :] + hfl_cfg.tiers[1].beta_up * eps_m  # [C, Qloc]
     vals_l, idx_l, eps_rows = [], [], []
     for c in range(C):  # static; C == N // num_pods, normally 1
-        vals, idx = sp.pack_phi(s[c], hfl_cfg.tiers[1].phi_up, impl=impl)
+        with jax.named_scope("sync.select"):
+            s_c = s[c]
+        vals, idx = sp.pack_phi(s_c, hfl_cfg.tiers[1].phi_up, impl=impl)
         if wire:
             # quantize BEFORE accounting the residual: eps must buffer the
             # wire quantization error too, since receivers only ever see
@@ -304,49 +344,57 @@ def _flat_shard_sync(params, w_ref, eps, e, *, hfl_cfg, wire):
             # flat/leaf paths and preserves exact drift conservation)
             vals = _wire_round(vals, wire)
         sent = sp.unpack_topk(vals, idx, Q)
-        eps_rows.append(s[c] - sent)
+        with jax.named_scope("sync.merge"):
+            eps_rows.append(s[c] - sent)
         vals_l.append(vals)
         idx_l.append(idx)
-    vals = jnp.stack(vals_l)  # [C, k]
-    idx = jnp.stack(idx_l)
+    with jax.named_scope("sync.compact"):
+        vals = jnp.stack(vals_l)  # [C, k]
+        idx = jnp.stack(idx_l)
 
     # --- cross-pod exchange: 2·C·k values per hop instead of C·Q ---
-    if wire == "bf16":
-        # lossless now (vals already round-tripped); the barriers pin the
-        # bf16 cast to THIS side of the gather: XLA's algebraic simplifier
-        # otherwise rewrites convert(all_gather(bf16)) into
-        # all_gather(f32), putting f32 back on the wire. (q8 values are
-        # already exact multiples of the scale; the gather stays f32 as a
-        # simulation artifact — the byte-accurate stream is the codec's.)
-        vals = jax.lax.optimization_barrier(vals.astype(jnp.bfloat16))
-    all_vals = jax.lax.all_gather(vals, "pod")  # [npod, C, k]
-    if wire == "bf16":
-        all_vals = jax.lax.optimization_barrier(all_vals)
-    all_idx = jax.lax.all_gather(idx, "pod")
-    delta = (
-        jnp.zeros((Q,), jnp.float32)
-        .at[all_idx.reshape(-1)]
-        .add(all_vals.reshape(-1).astype(jnp.float32))
-        / N
-    )
+    with jax.named_scope("sync.exchange"):
+        if wire == "bf16":
+            # lossless now (vals already round-tripped); the barriers pin
+            # the bf16 cast to THIS side of the gather: XLA's algebraic
+            # simplifier otherwise rewrites convert(all_gather(bf16)) into
+            # all_gather(f32), putting f32 back on the wire. (q8 values
+            # are already exact multiples of the scale; the gather stays
+            # f32 as a simulation artifact — the byte-accurate stream is
+            # the codec's.)
+            vals = jax.lax.optimization_barrier(vals.astype(jnp.bfloat16))
+        all_vals = jax.lax.all_gather(vals, "pod")  # [npod, C, k]
+        if wire == "bf16":
+            all_vals = jax.lax.optimization_barrier(all_vals)
+        all_idx = jax.lax.all_gather(idx, "pod")
+    with jax.named_scope("sync.merge"):
+        summed = (
+            jnp.zeros((Q,), jnp.float32)
+            .at[all_idx.reshape(-1)]
+            .add(all_vals.reshape(-1).astype(jnp.float32))
+        )
 
     # --- MBS side: discounted error + whole-vector top-k downlink ---
-    delta = delta + hfl_cfg.tiers[1].beta_down * e_v
+    with jax.named_scope("sync.exchange"):
+        mean = summed / N
+    with jax.named_scope("sync.select"):
+        delta = mean + hfl_cfg.tiers[1].beta_down * e_v
     dvals, didx = sp.pack_phi(delta, hfl_cfg.tiers[1].phi_down, impl=impl)
     if wire:
         dvals = _wire_round(dvals, wire)
     d = sp.unpack_topk(dvals, didx, Q)
-    new_e = delta - d
-    new_wref = wref + d
+    with jax.named_scope("sync.merge"):
+        new_e = delta - d
+        new_wref = wref + d
 
-    # --- clusters adopt the new reference ---
-    new_wn = jnp.broadcast_to(new_wref[None], (C, Q))
-    return (
-        fl.unpack_stacked(new_wn, p_spec),
-        fl.unpack(new_wref, ref_spec),
-        fl.unpack_stacked(jnp.stack(eps_rows), eps_spec),
-        fl.unpack(new_e, ref_spec),
-    )
+        # --- clusters adopt the new reference ---
+        new_wn = jnp.broadcast_to(new_wref[None], (C, Q))
+        return (
+            fl.unpack_stacked(new_wn, p_spec),
+            fl.unpack(new_wref, ref_spec),
+            fl.unpack_stacked(jnp.stack(eps_rows), eps_spec),
+            fl.unpack(new_e, ref_spec),
+        )
 
 
 # ---- fused flat layout: batched whole-model Ω via kernels/fused_sync ------
@@ -356,61 +404,66 @@ def _unpack_ref_outputs(new_wref, ref_spec, state: HFLState):
     """f32 flat reference -> (params, w_ref) trees WITHOUT routing params
     through the (possibly bf16) w_ref storage dtype: each leaf is cast
     straight f32 -> its own dtype, exactly like the unfused paths."""
-    wref_leaves = [
-        new_wref[ref_spec.leaf_slice(i)].reshape(ref_spec.shapes[i])
-        for i in range(len(ref_spec.sizes))
-    ]
-    wref_tree_f32 = jax.tree.unflatten(ref_spec.treedef, wref_leaves)
-    params = jax.tree.map(
-        lambda w, p: jnp.broadcast_to(w.astype(p.dtype)[None], p.shape),
-        wref_tree_f32,
-        state.params,
-    )
-    w_ref = jax.tree.map(
-        lambda w, r: w.astype(r.dtype), wref_tree_f32, state.w_ref
-    )
-    return params, w_ref
+    with jax.named_scope("sync.merge"):
+        wref_leaves = [
+            new_wref[ref_spec.leaf_slice(i)].reshape(ref_spec.shapes[i])
+            for i in range(len(ref_spec.sizes))
+        ]
+        wref_tree_f32 = jax.tree.unflatten(ref_spec.treedef, wref_leaves)
+        params = jax.tree.map(
+            lambda w, p: jnp.broadcast_to(w.astype(p.dtype)[None], p.shape),
+            wref_tree_f32,
+            state.params,
+        )
+        w_ref = jax.tree.map(
+            lambda w, r: w.astype(r.dtype), wref_tree_f32, state.w_ref
+        )
+        return params, w_ref
 
 
 def _pack_drift(state: HFLState, beta_s: float, *, shards: int = 1):
     """[N, Q'] drift matrix s = wn - wref + β_s·eps built leaf-by-leaf in
     ONE concat — the packed params/eps matrices are never materialized
-    separately, halving the [N, Q]-sized traffic of the sync prologue."""
+    separately, halving the [N, Q]-sized traffic of the sync prologue.
+    Part of ``sync.select``."""
     N = jax.tree.leaves(state.params)[0].shape[0]
     p_leaves = jax.tree.leaves(state.params)
     wr_leaves = jax.tree.leaves(state.w_ref)
     eps_leaves = jax.tree.leaves(state.eps)
-    s = jnp.concatenate(
-        [
-            (p.reshape(N, -1).astype(jnp.float32)
-             - w.reshape(-1).astype(jnp.float32)[None, :])
-            + beta_s * ep.reshape(N, -1).astype(jnp.float32)
-            for p, w, ep in zip(p_leaves, wr_leaves, eps_leaves)
-        ],
-        axis=1,
-    )
-    # spec from eps: the unpacked drift residual must keep eps' storage
-    # dtype (params may be a different dtype than the error buffers)
-    spec = fl.spec_of_stacked(state.eps, shards=shards)
-    if spec.pad:
-        s = jnp.pad(s, ((0, 0), (0, spec.pad)))
-    return s, spec
+    with jax.named_scope("sync.select"):
+        s = jnp.concatenate(
+            [
+                (p.reshape(N, -1).astype(jnp.float32)
+                 - w.reshape(-1).astype(jnp.float32)[None, :])
+                + beta_s * ep.reshape(N, -1).astype(jnp.float32)
+                for p, w, ep in zip(p_leaves, wr_leaves, eps_leaves)
+            ],
+            axis=1,
+        )
+        # spec from eps: the unpacked drift residual must keep eps' storage
+        # dtype (params may be a different dtype than the error buffers)
+        spec = fl.spec_of_stacked(state.eps, shards=shards)
+        if spec.pad:
+            s = jnp.pad(s, ((0, 0), (0, spec.pad)))
+        return s, spec
 
 
 def _scatter_rows(idx, vals, L: int):
     """Dense [N, L] matrix with ``out[n, idx[n, j]] += vals[n, j]``, as
     ONE flat 1-D scatter (a 2-D scatter serializes on XLA-CPU). Pad/
     out-of-range entries carry vals == 0, so clipping them is a numeric
-    no-op."""
+    no-op. Part of ``sync.merge``."""
     N = idx.shape[0]
-    flat_idx = (jnp.minimum(idx, L - 1)
-                + (jnp.arange(N, dtype=jnp.int32) * L)[:, None]).reshape(-1)
-    return (
-        jnp.zeros((N * L,), jnp.float32)
-        .at[flat_idx]
-        .add(vals.reshape(-1))
-        .reshape(N, L)
-    )
+    with jax.named_scope("sync.merge"):
+        flat_idx = (jnp.minimum(idx, L - 1)
+                    + (jnp.arange(N, dtype=jnp.int32) * L)[:, None]
+                    ).reshape(-1)
+        return (
+            jnp.zeros((N * L,), jnp.float32)
+            .at[flat_idx]
+            .add(vals.reshape(-1))
+            .reshape(N, L)
+        )
 
 
 def _make_flat_fused_local_sync(hfl_cfg, wire, collect_stats: bool = False):
@@ -430,40 +483,48 @@ def _make_flat_fused_local_sync(hfl_cfg, wire, collect_stats: bool = False):
     N = hfl_cfg.num_clusters
 
     def flat_sync(state: HFLState):
-        wref, ref_spec = fl.pack(state.w_ref)
-        e, _ = fl.pack(state.e)
+        with jax.named_scope("sync.select"):
+            wref, ref_spec = fl.pack(state.w_ref)
+            e, _ = fl.pack(state.e)
         Q = ref_spec.total
         s, eps_spec = _pack_drift(state, hfl_cfg.tiers[1].beta_up)
 
-        # --- SBS side: batched whole-vector Ω uplinks (Alg.5 l.24-27) ---
+        # --- SBS side: batched whole-vector Ω uplinks (Alg.5 l.24-27);
+        #     the fused selection gathers its payload itself ---
         k_ul = sp.keep_count(Q, hfl_cfg.tiers[1].phi_up)
-        vals, idx = fops.select_topk_rows(s, k_ul)  # [N, k]
+        with jax.named_scope("sync.select"):
+            vals, idx = fops.select_topk_rows(s, k_ul)  # [N, k]
         if wire:
             vals = _wire_round_rows(vals, wire)
         # ONE flat scatter materializes all N sent rows; the error update
         # and the consensus mean stay dense elementwise ops XLA fuses
         sents = _scatter_rows(idx, vals, Q)
-        new_eps = s - sents
+        with jax.named_scope("sync.merge"):
+            new_eps = s - sents
 
         # --- MBS side: consensus + discounted error + Ω downlink ---
-        delta = jnp.mean(sents, axis=0) + hfl_cfg.tiers[1].beta_down * e
+        with jax.named_scope("sync.exchange"):
+            mean = jnp.mean(sents, axis=0)
         k_dl = sp.keep_count(Q, hfl_cfg.tiers[1].phi_down)
-        dvals, didx = fops.select_topk_rows(delta[None, :], k_dl)
-        dvals, didx = dvals[0], didx[0]
+        with jax.named_scope("sync.select"):
+            delta = mean + hfl_cfg.tiers[1].beta_down * e
+            dvals, didx = fops.select_topk_rows(delta[None, :], k_dl)
+            dvals, didx = dvals[0], didx[0]
         if wire:
             dvals = _wire_round(dvals, wire)
-        d = jnp.zeros((Q,), jnp.float32).at[didx].add(dvals)
-        new_e = delta - d
-        new_wref = wref + d
-
+        with jax.named_scope("sync.merge"):
+            d = jnp.zeros((Q,), jnp.float32).at[didx].add(dvals)
+            new_e = delta - d
+            new_wref = wref + d
         # --- clusters adopt the new reference (Alg.5 l.33/43) ---
         params, w_ref = _unpack_ref_outputs(new_wref, ref_spec, state)
-        new_state = state._replace(
-            params=params,
-            w_ref=w_ref,
-            eps=fl.unpack_stacked(new_eps, eps_spec),
-            e=fl.unpack(new_e, ref_spec),
-        )
+        with jax.named_scope("sync.merge"):
+            new_state = state._replace(
+                params=params,
+                w_ref=w_ref,
+                eps=fl.unpack_stacked(new_eps, eps_spec),
+                e=fl.unpack(new_e, ref_spec),
+            )
         if not collect_stats:
             return new_state
         # the fused prologue never materializes the stacked params matrix
@@ -488,28 +549,30 @@ def _sharded_select(s, k: int, S: int, L: int, size: int, *, gathered=None):
     th) already stacked shard-major [S, R, ...] from its all-gather. The
     merge is identical either way, so the mesh execution and the local
     emulation are bit-identical. Returns (vals [R, k], idx [R, k], exact).
+    Part of ``sync.select``: the selection gathers its payload itself.
     """
     from repro.kernels.fused_sync import ops as fops
 
-    if gathered is None:
-        parts = []
-        for sh in range(S):
-            sl = s[:, sh * L:(sh + 1) * L]
-            v, i, m, th = fops.shard_select_candidates(sl, k, S)
-            gi = jnp.where(i < L, i + sh * L, size)
-            parts.append((v, gi, m, th))
-        cand_v = jnp.stack([p[0] for p in parts])  # [S, R, cap_s]
-        cand_i = jnp.stack([p[1] for p in parts])
-        m = jnp.stack([p[2] for p in parts])  # [S, R]
-        th = jnp.stack([p[3] for p in parts])
-    else:
-        cand_v, cand_i, m, th = gathered
-    R = cand_v.shape[1]
-    cand_v = jnp.transpose(cand_v, (1, 0, 2)).reshape(R, -1)  # shard-major
-    cand_i = jnp.transpose(cand_i, (1, 0, 2)).reshape(R, -1)
-    return fops.merge_shard_candidates(
-        cand_v, cand_i, jnp.transpose(m), jnp.transpose(th), k
-    )
+    with jax.named_scope("sync.select"):
+        if gathered is None:
+            parts = []
+            for sh in range(S):
+                sl = s[:, sh * L:(sh + 1) * L]
+                v, i, m, th = fops.shard_select_candidates(sl, k, S)
+                gi = jnp.where(i < L, i + sh * L, size)
+                parts.append((v, gi, m, th))
+            cand_v = jnp.stack([p[0] for p in parts])  # [S, R, cap_s]
+            cand_i = jnp.stack([p[1] for p in parts])
+            m = jnp.stack([p[2] for p in parts])  # [S, R]
+            th = jnp.stack([p[3] for p in parts])
+        else:
+            cand_v, cand_i, m, th = gathered
+        R = cand_v.shape[1]
+        cand_v = jnp.transpose(cand_v, (1, 0, 2)).reshape(R, -1)  # shard-major
+        cand_i = jnp.transpose(cand_i, (1, 0, 2)).reshape(R, -1)
+        return fops.merge_shard_candidates(
+            cand_v, cand_i, jnp.transpose(m), jnp.transpose(th), k
+        )
 
 
 def _make_flat_sharded_local_sync(hfl_cfg, wire, shards: int):
@@ -523,8 +586,9 @@ def _make_flat_sharded_local_sync(hfl_cfg, wire, shards: int):
     N, S = hfl_cfg.num_clusters, shards
 
     def sharded_sync(state: HFLState):
-        wref, ref_spec = fl.pack(state.w_ref, shards=S)
-        e, _ = fl.pack(state.e, shards=S)
+        with jax.named_scope("sync.select"):
+            wref, ref_spec = fl.pack(state.w_ref, shards=S)
+            e, _ = fl.pack(state.e, shards=S)
         Q, Qp = ref_spec.total, ref_spec.padded_total
         L = ref_spec.local_size
         s, eps_spec = _pack_drift(state, hfl_cfg.tiers[1].beta_up, shards=S)
@@ -539,25 +603,33 @@ def _make_flat_sharded_local_sync(hfl_cfg, wire, shards: int):
         if wire:
             vals = _wire_round_rows(vals, wire)
         sents = _scatter_rows(idx, vals, Qp)
-        new_eps = s - sents
-        delta = jnp.mean(sents, axis=0) + hfl_cfg.tiers[1].beta_down * e
+        with jax.named_scope("sync.merge"):
+            new_eps = s - sents
+        with jax.named_scope("sync.exchange"):
+            mean = jnp.mean(sents, axis=0)
+        with jax.named_scope("sync.select"):
+            delta = mean + hfl_cfg.tiers[1].beta_down * e
 
         k_dl = sp.keep_count(Q, hfl_cfg.tiers[1].phi_down)
         dvals, didx, _exact_d = _sharded_select(delta[None, :], k_dl, S, L, Qp)
-        dvals, didx = dvals[0], didx[0]
+        with jax.named_scope("sync.select"):
+            dvals, didx = dvals[0], didx[0]
         if wire:
             dvals = _wire_round(dvals, wire)
-        d = _scatter_rows(didx[None, :], dvals[None, :], Qp)[0]
-        new_e = delta - d
-        new_wref = wref + d
+        d = _scatter_rows(didx[None, :], dvals[None, :], Qp)
+        with jax.named_scope("sync.merge"):
+            d = d[0]
+            new_e = delta - d
+            new_wref = wref + d
 
         params, w_ref = _unpack_ref_outputs(new_wref, ref_spec, state)
-        return state._replace(
-            params=params,
-            w_ref=w_ref,
-            eps=fl.unpack_stacked(new_eps, eps_spec),
-            e=fl.unpack(new_e, ref_spec),
-        )
+        with jax.named_scope("sync.merge"):
+            return state._replace(
+                params=params,
+                w_ref=w_ref,
+                eps=fl.unpack_stacked(new_eps, eps_spec),
+                e=fl.unpack(new_e, ref_spec),
+            )
 
     return sharded_sync
 
@@ -598,58 +670,70 @@ def _make_flat_sharded_sync(hfl_cfg, wire, mesh):
         return lin * L
 
     def body(s, wref, e, *, Q, Qp, L):
-        # s [N, L]; wref/e [L] — this device's contiguous piece
+        # s [N, L]; wref/e [L] — this device's contiguous piece. The
+        # candidates' all-gather inside a pod is part of the selection; the
+        # clusters' payloads combine in the mean (sync.exchange).
         k_ul = sp.keep_count(Q, hfl_cfg.tiers[1].phi_up)
-        off = shard_offset(L)
-        v, i, m, th = fops.shard_select_candidates(s, k_ul, S)
-        gi = jnp.where(i < L, i + off, Qp)
-        gathered = tuple(
-            gather_shard_major(t) for t in (v, gi, m, th)
-        )  # [S, N, cap_s] / [S, N]
+        with jax.named_scope("sync.select"):
+            off = shard_offset(L)
+            v, i, m, th = fops.shard_select_candidates(s, k_ul, S)
+            gi = jnp.where(i < L, i + off, Qp)
+            gathered = tuple(
+                gather_shard_major(t) for t in (v, gi, m, th)
+            )  # [S, N, cap_s] / [S, N]
         vals, idx, _exact = _sharded_select(
             None, k_ul, S, L, Qp, gathered=gathered
         )
         if wire:
             vals = _wire_round_rows(vals, wire)
         # scatter only the indices living on THIS shard (others no-op)
-        loc = idx - off
-        inb = (loc >= 0) & (loc < L)
-        sents = _scatter_rows(
-            jnp.where(inb, loc, L - 1), jnp.where(inb, vals, 0.0), L
-        )
-        new_eps = s - sents
-        delta = jnp.mean(sents, axis=0) + hfl_cfg.tiers[1].beta_down * e
+        with jax.named_scope("sync.merge"):
+            loc = idx - off
+            inb = (loc >= 0) & (loc < L)
+            loc, vals = jnp.where(inb, loc, L - 1), jnp.where(inb, vals, 0.0)
+        sents = _scatter_rows(loc, vals, L)
+        with jax.named_scope("sync.merge"):
+            new_eps = s - sents
+        with jax.named_scope("sync.exchange"):
+            mean = jnp.mean(sents, axis=0)
 
         k_dl = sp.keep_count(Q, hfl_cfg.tiers[1].phi_down)
-        dv, di, dm, dth = fops.shard_select_candidates(delta[None, :], k_dl, S)
-        dgi = jnp.where(di < L, di + off, Qp)
-        dg = tuple(gather_shard_major(t) for t in (dv, dgi, dm, dth))
+        with jax.named_scope("sync.select"):
+            delta = mean + hfl_cfg.tiers[1].beta_down * e
+            dv, di, dm, dth = fops.shard_select_candidates(delta[None, :],
+                                                           k_dl, S)
+            dgi = jnp.where(di < L, di + off, Qp)
+            dg = tuple(gather_shard_major(t) for t in (dv, dgi, dm, dth))
         dvals, didx, _exact_d = _sharded_select(
             None, k_dl, S, L, Qp, gathered=dg
         )
-        dvals, didx = dvals[0], didx[0]
+        with jax.named_scope("sync.select"):
+            dvals, didx = dvals[0], didx[0]
         if wire:
             dvals = _wire_round(dvals, wire)
-        dloc = didx - off
-        dinb = (dloc >= 0) & (dloc < L)
-        d = _scatter_rows(
-            jnp.where(dinb, dloc, L - 1)[None, :],
-            jnp.where(dinb, dvals, 0.0)[None, :],
-            L,
-        )[0]
-        new_e = delta - d
-        new_wref = wref + d
+        with jax.named_scope("sync.merge"):
+            dloc = didx - off
+            dinb = (dloc >= 0) & (dloc < L)
+            dloc = jnp.where(dinb, dloc, L - 1)[None, :]
+            dvals = jnp.where(dinb, dvals, 0.0)[None, :]
+        d = _scatter_rows(dloc, dvals, L)
+        with jax.named_scope("sync.merge"):
+            d = d[0]
+            new_e = delta - d
+            new_wref = wref + d
         return new_eps, new_wref, new_e
 
     def sharded_sync(state: HFLState):
-        wref, ref_spec = fl.pack(state.w_ref, shards=S)
-        e, _ = fl.pack(state.e, shards=S)
+        with jax.named_scope("sync.select"):
+            wref, ref_spec = fl.pack(state.w_ref, shards=S)
+            e, _ = fl.pack(state.e, shards=S)
         Q, Qp, L = ref_spec.total, ref_spec.padded_total, ref_spec.local_size
         s, eps_spec = _pack_drift(state, hfl_cfg.tiers[1].beta_up, shards=S)
         vec = P(axes if len(axes) > 1 else axes[0])
         mat = P(None, *vec)
-        s = jax.lax.with_sharding_constraint(
-            s, jax.sharding.NamedSharding(mesh, mat))
+        with jax.named_scope("sync.select"):
+            s = jax.lax.with_sharding_constraint(
+                s, jax.sharding.NamedSharding(mesh, mat))
         sm = jaxcompat.shard_map(
             partial(body, Q=Q, Qp=Qp, L=L),
             mesh=mesh,
@@ -658,12 +742,13 @@ def _make_flat_sharded_sync(hfl_cfg, wire, mesh):
         )
         new_eps, new_wref, new_e = sm(s, wref, e)
         params, w_ref = _unpack_ref_outputs(new_wref, ref_spec, state)
-        return state._replace(
-            params=params,
-            w_ref=w_ref,
-            eps=fl.unpack_stacked(new_eps, eps_spec),
-            e=fl.unpack(new_e, ref_spec),
-        )
+        with jax.named_scope("sync.merge"):
+            return state._replace(
+                params=params,
+                w_ref=w_ref,
+                eps=fl.unpack_stacked(new_eps, eps_spec),
+                e=fl.unpack(new_e, ref_spec),
+            )
 
     return sharded_sync
 
@@ -1169,6 +1254,11 @@ def jit_sync_step(sync_step):
     rebind: ``state = sync(state)``; touching the old state afterwards
     raises on deleted buffers.
 
+    Inputs the sync does not read are kept (``keep_unused``): the dense
+    sync reads ``w_ref`` only for its dtype, and were it pruned, JAX would
+    hand its donated slot to the next buffer of the same shape, ``e``,
+    which XLA then copies whole to return it unchanged.
+
     A sync built with ``collect_stats=True`` returns ``(state, stats)``;
     the flag is propagated onto the jitted callable so callers handed a
     pre-built step (the engine) can detect the return shape with
@@ -1181,7 +1271,7 @@ def jit_sync_step(sync_step):
     """
     if getattr(sync_step, "hier", False):
         return sync_step
-    jitted = jax.jit(sync_step, donate_argnums=0)
+    jitted = jax.jit(sync_step, donate_argnums=0, keep_unused=True)
     jitted.collect_stats = bool(getattr(sync_step, "collect_stats", False))
     return jitted
 
@@ -1298,18 +1388,23 @@ def make_sync(plan: SyncPlan):
         N = hfl_cfg.num_clusters
 
         def dense_sync(state: HFLState):
-            w_mean = jax.tree.map(lambda p: jnp.mean(p.astype(jnp.float32), axis=0), state.params)
-            new_params = jax.tree.map(
-                lambda m, p: jnp.broadcast_to(m[None].astype(p.dtype), p.shape),
-                w_mean,
-                state.params,
-            )
-            # cast back to the buffer dtype chosen at hfl_init: writing the
-            # f32 mean verbatim would flip a bf16 w_ref to f32 after the
-            # first sync and retrace every jitted step each period
-            new_wref = jax.tree.map(
-                lambda m, r: m.astype(r.dtype), w_mean, state.w_ref
-            )
+            with jax.named_scope("sync.exchange"):
+                w_mean = jax.tree.map(
+                    lambda p: jnp.mean(p.astype(jnp.float32), axis=0),
+                    state.params)
+            with jax.named_scope("sync.merge"):
+                new_params = jax.tree.map(
+                    lambda m, p: jnp.broadcast_to(m[None].astype(p.dtype),
+                                                  p.shape),
+                    w_mean,
+                    state.params,
+                )
+                # cast back to the buffer dtype chosen at hfl_init: writing
+                # the f32 mean verbatim would flip a bf16 w_ref to f32 after
+                # the first sync and retrace every jitted step each period
+                new_wref = jax.tree.map(
+                    lambda m, r: m.astype(r.dtype), w_mean, state.w_ref
+                )
             new_state = state._replace(params=new_params, w_ref=new_wref)
             if not collect_stats:
                 return new_state

@@ -13,6 +13,11 @@ schedule, with virtual time attached. Callers that want the wall-clock /
 scenario machinery (stragglers, mobility, dropout, async) build a
 ``SimEngine`` via ``repro.sim.scenarios`` and call ``engine.run`` directly,
 which also returns the trace.
+
+The whole call is the profiler span ``hfl.run``; the engine opens the
+per-step spans inside it (``hfl.step``, ``hfl.round``, ``hfl.batch``,
+``hfl.train``, ``hfl.sync``, ``hfl.on_step``), so a running
+``jax.profiler`` trace shows where the host spent each step.
 """
 from __future__ import annotations
 
@@ -39,10 +44,12 @@ def run_hfl(
     on their own per-tier periods (``hier_fire_top``); with an async root
     tier the run switches to the mixed-discipline event loop.
     """
+    from repro.obs.spans import profiler_span
     from repro.sim.engine import SimEngine
 
-    engine = SimEngine(period=period, record=False)
-    state, _trace = engine.run(
-        state, train_step, sync_step, batches, num_steps, on_step=on_step
-    )
+    with profiler_span("hfl.run"):
+        engine = SimEngine(period=period, record=False)
+        state, _trace = engine.run(
+            state, train_step, sync_step, batches, num_steps, on_step=on_step
+        )
     return state

@@ -14,6 +14,11 @@ zeroes the rest. Two selection implementations:
 
 All functions operate on a single array (a leaf or a flat vector); pytree
 orchestration lives in ``repro.core.hfl``.
+
+The exchange-payload helpers put their ops under the sync's named scopes,
+which the profiler's trace carries as metadata: ``sync.select`` (choosing
+Ω), ``sync.compact`` (gathering the kept values and indices into the
+payload) and ``sync.merge`` (scattering a payload back to dense).
 """
 from __future__ import annotations
 
@@ -139,13 +144,16 @@ def dgc_step(u, v, g, sigma: float, phi: float, *, impl: str = "topk"):
 def pack_topk(x, k: int):
     """-> (values [k], indices [k] int32) of the k largest-|x| entries."""
     flat = x.reshape(-1)
-    _, idx = jax.lax.top_k(jnp.abs(flat), k)
-    return flat[idx], idx.astype(jnp.int32)
+    with jax.named_scope("sync.select"):
+        _, idx = jax.lax.top_k(jnp.abs(flat), k)
+    with jax.named_scope("sync.compact"):
+        return flat[idx], idx.astype(jnp.int32)
 
 
 def unpack_topk(values, indices, size: int, shape=None):
-    out = jnp.zeros((size,), values.dtype).at[indices].add(values)
-    return out.reshape(shape) if shape is not None else out
+    with jax.named_scope("sync.merge"):
+        out = jnp.zeros((size,), values.dtype).at[indices].add(values)
+        return out.reshape(shape) if shape is not None else out
 
 
 def compact_mask(x, mask, k: int):
@@ -159,14 +167,15 @@ def compact_mask(x, mask, k: int):
     overshoot is at most one bin's worth); if fewer, the spare slots hold
     (value 0, index 0), which scatter-add treats as a no-op.
     """
-    flat = x.reshape(-1)
-    m = mask.reshape(-1)
-    pos = jnp.cumsum(m.astype(jnp.int32)) - 1
-    tgt = jnp.where(m & (pos < k), pos, k)  # k == out-of-bounds -> dropped
-    iota = jnp.arange(flat.size, dtype=jnp.int32)
-    idx = jnp.zeros((k,), jnp.int32).at[tgt].set(iota, mode="drop")
-    vals = jnp.zeros((k,), flat.dtype).at[tgt].set(flat, mode="drop")
-    return vals, idx
+    with jax.named_scope("sync.compact"):
+        flat = x.reshape(-1)
+        m = mask.reshape(-1)
+        pos = jnp.cumsum(m.astype(jnp.int32)) - 1
+        tgt = jnp.where(m & (pos < k), pos, k)  # k == out-of-bounds -> dropped
+        iota = jnp.arange(flat.size, dtype=jnp.int32)
+        idx = jnp.zeros((k,), jnp.int32).at[tgt].set(iota, mode="drop")
+        vals = jnp.zeros((k,), flat.dtype).at[tgt].set(flat, mode="drop")
+        return vals, idx
 
 
 def pack_phi(x, phi: float, *, impl: str = "topk", bins: int = 64):
@@ -182,22 +191,26 @@ def pack_phi(x, phi: float, *, impl: str = "topk", bins: int = 64):
       * ``fused``  -- threshold select + compaction + finisher
                       (``repro.kernels.fused_sync``): selection
                       bit-identical to ``topk`` without its full sort
+
+    The fused path selects and gathers its payload in one pass, all of it
+    charged to ``sync.select``.
     """
     flat = x.reshape(-1)
     k = keep_count(flat.size, phi)
     if impl == "topk":
         return pack_topk(flat, k)
-    if impl == "fused":
-        from repro.kernels.fused_sync import ops as _f
+    with jax.named_scope("sync.select"):
+        if impl == "fused":
+            from repro.kernels.fused_sync import ops as _f
 
-        return _f.fused_pack_phi(flat, phi, bins=bins)
-    if impl == "hist":
-        mask = threshold_mask(flat, phi, bins=bins)
-    elif impl == "pallas":
-        from repro.kernels.dgc import ops as _k
+            return _f.fused_pack_phi(flat, phi, bins=bins)
+        if impl == "hist":
+            mask = threshold_mask(flat, phi, bins=bins)
+        elif impl == "pallas":
+            from repro.kernels.dgc import ops as _k
 
-        th = _k.threshold_pallas(flat, phi, bins=bins)
-        mask = mask_at_least_k(flat, th, k)
-    else:
-        raise ValueError(impl)
+            th = _k.threshold_pallas(flat, phi, bins=bins)
+            mask = mask_at_least_k(flat, th, k)
+        else:
+            raise ValueError(impl)
     return compact_mask(flat, mask, k)

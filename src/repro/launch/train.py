@@ -19,10 +19,10 @@ wall-clock-vs-loss trace (``--trace-out`` to save it as JSON):
 
 Observability (``repro.obs``): ``--trace-viz out.json`` exports a
 Chrome/Perfetto trace of every simulator event on the virtual clock plus
-host-clock jit-boundary spans; ``--metrics-out run.jsonl`` streams every
+host-clock spans of the engine's host path (also recorded by a running
+``jax.profiler`` trace); ``--metrics-out run.jsonl`` streams every
 console line as a structured JSONL event and appends the final metrics-
-registry snapshot; ``--obs-hlo-cost`` adds HLO flop/byte/launch and
-device-memory analysis of the compiled steps; ``--obs-health`` turns on the learning-health
+registry snapshot; ``--obs-health`` turns on the learning-health
 monitor (per-cluster drift/residual/Ω-overlap from the jitted sync,
 staleness + participation fairness from the simulator, streaming anomaly
 rules -> JSONL ``health`` events + Perfetto counter tracks). Reporting also
@@ -176,11 +176,6 @@ def main(argv=None):
     ap.add_argument("--obs-heartbeat", type=int, default=0,
                     help="print an events/s + live-memory heartbeat to "
                          "stderr every N simulator events (0 = off)")
-    ap.add_argument("--obs-hlo-cost", action="store_true",
-                    help="analyze the jitted train/sync steps' HLO "
-                         "(flops, HBM bytes, collective bytes, launch "
-                         "count, device memory) after the run, from the "
-                         "programs the run compiled")
     ap.add_argument("--obs-health", action="store_true",
                     help="learning-health monitor: per-cluster consensus "
                          "drift / residual norms / Ω overlap from the "
@@ -194,11 +189,10 @@ def main(argv=None):
 
     obs_cfg = None
     if (args.trace_viz or args.metrics_out or args.obs_heartbeat
-            or args.obs_hlo_cost or args.obs_health):
+            or args.obs_health):
         obs_cfg = ObsConfig(
             trace_path=args.trace_viz, metrics_path=args.metrics_out,
             heartbeat_events=args.obs_heartbeat,
-            hlo_cost=bool(args.obs_hlo_cost),
             health=bool(args.obs_health))
     log = RunLogger(args.metrics_out)
 
@@ -410,28 +404,6 @@ def train(cfg, hfl, *, steps: int, batch_per_mu: int = 8, seq: int = 64,
     else:
         state = run_hfl(state, train_step, sync_step, make_batches(lm, rng),
                         hfl.tiers[1].period, steps, on_step)
-
-    costs = {}
-    if obs_cfg is not None and obs_cfg.hlo_cost:
-        from repro.obs import program_costs
-        # after the run: lowering the steps again finds the programs the
-        # run compiled, so the analysis costs no extra compile. The probe
-        # batch comes from an independent generator with the data seeds.
-        probe = next(make_batches(SyntheticLM(cfg.vocab_size, seed=1),
-                                  np.random.default_rng(2)))
-        costs["train_step"] = program_costs(train_step, state, probe)
-        if not getattr(sync_step, "hier", False):
-            # a depth > 2 sync is one program per tier boundary
-            costs["sync_step"] = program_costs(sync_step, state)
-        for k, c in costs.items():
-            log.log("hlo_cost",
-                    f"[obs] {k}: {c['flops']/1e9:.3f} GFLOP "
-                    f"{c['hbm_bytes']/1e6:.1f} MB HBM "
-                    f"{c['launches']} launches; device memory "
-                    f"args={c['argument_bytes']/1e9:.3f} GB "
-                    f"out={c['output_bytes']/1e9:.3f} GB "
-                    f"alias={c['alias_bytes']/1e9:.3f} GB "
-                    f"temp={c['temp_bytes']/1e9:.3f} GB", fn=k, **c)
 
     timing = clock.summary()
     if timing["steps"]:

@@ -25,8 +25,16 @@ def flash_attention(q, k, v, *, q_offset=0, window=0, q_chunk=512, kv_chunk=512)
 
     ``window`` > 0 enables sliding-window masking (key kept iff
     q_pos - window < k_pos <= q_pos). ``q_offset`` is the absolute position of
-    q[0] (k positions start at 0).
+    q[0] (k positions start at 0). Its ops sit under the named scope
+    ``attention``, in the forward pass and, under ``transpose(...)``, in the
+    backward one.
     """
+    with jax.named_scope("attention"):
+        return _flash_attention(q, k, v, q_offset=q_offset, window=window,
+                                q_chunk=q_chunk, kv_chunk=kv_chunk)
+
+
+def _flash_attention(q, k, v, *, q_offset, window, q_chunk, kv_chunk):
     B, T, H, D = q.shape
     S, Hkv = k.shape[1], k.shape[2]
     G = H // Hkv

@@ -13,8 +13,8 @@ Public surface:
     (wireless pricing, sync-step builders).
   * ``SpanTracer`` / ``validate_trace`` — virtual+host clock spans,
     Chrome/Perfetto trace-event JSON export.
-  * ``StepClock`` / ``program_costs`` / ``live_bytes`` — compile vs
-    steady step timing, HLO cost/launch counts, live-memory probe.
+  * ``StepClock`` / ``live_bytes`` — compile vs steady step timing,
+    live-memory probe.
   * ``RunLogger`` — console + JSONL structured run log.
 """
 from repro.obs.config import ObsConfig
@@ -22,7 +22,7 @@ from repro.obs.health import (
     DEFAULT_RULES, NULL_HEALTH, HealthMonitor, NullHealthMonitor, Rule,
     Window,
 )
-from repro.obs.jaxprof import StepClock, live_bytes, program_costs
+from repro.obs.jaxprof import StepClock, live_bytes
 from repro.obs.metrics import (
     NULL_REGISTRY, MetricsRegistry, current_registry, set_registry,
     use_registry,
@@ -39,7 +39,7 @@ from repro.obs.telemetry import (
 )
 
 __all__ = [
-    "ObsConfig", "StepClock", "live_bytes", "program_costs",
+    "ObsConfig", "StepClock", "live_bytes",
     "NULL_REGISTRY", "MetricsRegistry", "current_registry", "set_registry",
     "use_registry", "RunLogger", "HOST_PID", "VIRTUAL_PID", "SpanTracer",
     "to_jsonable", "validate_trace", "NULL_TELEMETRY", "NullTelemetry",

@@ -8,8 +8,9 @@ carries it from the CLI (``launch/train.py``) through
 ``obs=None`` / ``enabled=False`` resolve to the shared null telemetry
 (``repro.obs.telemetry.NULL_TELEMETRY``): every emit site in the hot loops
 is guarded by one attribute check (``obs.enabled``), so a run without
-observability pays nothing and replays bit-identically (tracing only ever
-*reads* engine state; it never touches the RNG or the virtual clock).
+observability pays nothing but the host spans' profiler annotations (about
+a microsecond each) and replays bit-identically (tracing only ever *reads*
+engine state; it never touches the RNG or the virtual clock).
 """
 from __future__ import annotations
 
@@ -29,16 +30,15 @@ class ObsConfig:
     # structured run-log JSONL path (--metrics-out); consumed by
     # launch/train.py's RunLogger, carried here so one config travels
     metrics_path: Optional[str] = None
-    # host-clock spans around the engine's jit boundaries (train/sync
-    # dispatch). Durations measure *dispatch* time — jax runs async — so
-    # the first call shows trace+compile and steady calls show enqueue.
+    # host-clock Chrome events (pid 2) of the engine's host spans (hfl.step,
+    # hfl.round, hfl.batch, hfl.train, hfl.sync, hfl.on_step). Durations
+    # measure *dispatch* time — jax runs async — so the first call shows
+    # trace+compile and steady calls show enqueue. The spans are profiler
+    # annotations whether or not this is on.
     host_spans: bool = True
     # emit a live events/s + live-bytes heartbeat every N engine events
     # (gauges in the registry + one stderr line); 0 = off
     heartbeat_events: int = 0
-    # after the run, analyze the compiled train/sync steps: flops/bytes/
-    # launch counts via launch/hlo_cost, and their device memory
-    hlo_cost: bool = False
     # span-event cap: fleet-scale runs keep the trace bounded. Past the
     # cap events are counted (``dropped_events`` in the export metadata)
     # but not stored; per-link bit accumulation continues regardless, so

@@ -32,7 +32,6 @@ EVENT_SCHEMAS = {
     "config": ("arch", "clusters", "mus_per_cluster", "period", "sync",
                "steps"),
     "sampling": (),
-    "hlo_cost": ("fn",),
     "step": ("step", "loss"),
     "sim_summary": ("discipline", "residency"),
     "sim_measured": (),

@@ -10,6 +10,12 @@ Two clock domains, rendered as two trace "processes":
     boundaries (span start is captured on ``__enter__``), so compile
     stalls and dispatch cost line up against the virtual timeline.
 
+Every host span is also a ``jax.profiler.TraceAnnotation``
+(``profiler_span``), so a running profiler records it in the device
+trace's host plane, on the clock the device's ops are timed by, where the
+chip's idle gaps can be split by what the host was doing. With no profiler
+running an annotation costs about a microsecond.
+
 Tracks ("threads") are named lazily — ``cluster3``, ``link:mu_ul``,
 ``fronthaul``, ``fleet``, ``engine`` — and emitted as ``thread_name``
 metadata events, one track per cluster/link per the trace-viz contract.
@@ -32,6 +38,8 @@ from __future__ import annotations
 import json
 import time
 
+from jax import profiler as _profiler
+
 VIRTUAL_PID = 1
 HOST_PID = 2
 PROCESS_NAMES = {VIRTUAL_PID: "virtual clock (HCN)", HOST_PID: "host clock"}
@@ -39,40 +47,39 @@ PROCESS_NAMES = {VIRTUAL_PID: "virtual clock (HCN)", HOST_PID: "host clock"}
 _REQUIRED_KEYS = ("name", "ph", "pid", "tid", "ts")
 
 
+def profiler_span(name: str, step=None):
+    """A span on the profiler's clock: ``jax.profiler.TraceAnnotation``,
+    or with ``step`` a ``StepTraceAnnotation`` carrying the step number."""
+    if step is None:
+        return _profiler.TraceAnnotation(name)
+    return _profiler.StepTraceAnnotation(name, step_num=step)
+
+
 class _HostSpan:
-    """Context manager emitting one host-clock complete event."""
+    """Context manager emitting one host-clock complete event, inside a
+    ``profiler_span`` of the same name."""
 
-    __slots__ = ("tracer", "name", "track", "t0")
+    __slots__ = ("tracer", "name", "track", "step", "t0", "ann")
 
-    def __init__(self, tracer, name, track):
+    def __init__(self, tracer, name, track, step=None):
         self.tracer, self.name, self.track = tracer, name, track
+        self.step = step
 
     def __enter__(self):
+        self.ann = profiler_span(self.name, self.step)
+        self.ann.__enter__()
         self.t0 = time.perf_counter()
         return self
 
     def __exit__(self, *exc):
+        t1 = time.perf_counter()
+        self.ann.__exit__(*exc)
         tr = self.tracer
         t0 = self.t0 - tr.host_t0
-        tr.span(self.name, track=self.track, t0=t0,
-                dur=time.perf_counter() - tr.host_t0 - t0,
-                pid=HOST_PID, cat="host")
+        tr.span(self.name, track=self.track, t0=t0, dur=t1 - self.t0,
+                pid=HOST_PID, cat="host",
+                args=None if self.step is None else {"step": self.step})
         return False
-
-
-class _NullSpan:
-    """Shared no-op context manager for the disabled path."""
-
-    __slots__ = ()
-
-    def __enter__(self):
-        return self
-
-    def __exit__(self, *exc):
-        return False
-
-
-NULL_SPAN = _NullSpan()
 
 
 class SpanTracer:
@@ -153,9 +160,10 @@ class SpanTracer:
                   track=track if track is not None else f"link:{link}",
                   t0=t0, dur=dur, cat="comm", args=a)
 
-    def host_span(self, name: str, track: str = "engine") -> _HostSpan:
+    def host_span(self, name: str, track: str = "engine",
+                  step=None) -> _HostSpan:
         """Host-clock span context manager (engine/jit boundaries)."""
-        return _HostSpan(self, name, track)
+        return _HostSpan(self, name, track, step)
 
     def reset_run(self) -> None:
         """Fresh per-run accumulators (the ledger is also rebuilt per

@@ -20,7 +20,7 @@ from repro.obs.health import NULL_HEALTH, HealthMonitor
 from repro.obs.metrics import (
     NULL_REGISTRY, MetricsRegistry, NullRegistry, set_registry,
 )
-from repro.obs.spans import NULL_SPAN, SpanTracer
+from repro.obs.spans import SpanTracer, profiler_span
 
 
 class Telemetry:
@@ -50,12 +50,14 @@ class Telemetry:
 
     # --- spans ------------------------------------------------------------
 
-    def host_span(self, name: str, track: str = "engine"):
-        """Host-clock span around a jit boundary; no-op when host spans
-        are configured off (virtual tracing can stay on alone)."""
+    def host_span(self, name: str, track: str = "engine", step=None):
+        """Span around a stretch of the engine's host path: always on the
+        profiler's clock (``profiler_span``), and also a host-clock Chrome
+        event when host spans are configured on (virtual tracing can stay
+        on alone). ``step`` makes it a step span carrying that number."""
         if not self.host:
-            return NULL_SPAN
-        return self.tracer.host_span(name, track=track)
+            return profiler_span(name, step)
+        return self.tracer.host_span(name, track=track, step=step)
 
     # --- run lifecycle ----------------------------------------------------
 
@@ -105,9 +107,9 @@ class NullTelemetry:
     """Disabled telemetry: every emit is a no-op, every guard is False.
 
     One shared instance serves all disabled runs; ``host_span`` returns a
-    shared context manager and no method allocates, so the disabled path
-    costs one attribute check at the guarded sites and nothing at all in
-    memory."""
+    profiler annotation, which a running profiler records and which costs
+    about a microsecond otherwise; every other method is a no-op, so the
+    disabled path costs one attribute check at the guarded sites."""
 
     enabled = False
     host = False
@@ -116,8 +118,8 @@ class NullTelemetry:
     tracer = None
     health = NULL_HEALTH
 
-    def host_span(self, name: str, track: str = "engine"):
-        return NULL_SPAN
+    def host_span(self, name: str, track: str = "engine", step=None):
+        return profiler_span(name, step)
 
     def reset_run(self) -> None:
         pass

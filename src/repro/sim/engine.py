@@ -1296,6 +1296,70 @@ class SimEngine:
 
     # --- lockstep / deadline ---------------------------------------------
 
+    def _price_sync(self, step: int, state, hbufs, ctx: dict, sync_step,
+                    hier: bool, N):
+        """The virtual-clock price of the sync at the end of ``step``,
+        measured from the state before the donating sync step consumes it:
+        -> (top, sync_s, sync_ul, sync_dl, bcast_b, fh_parts, legs,
+        row_extra)."""
+        sync_s = ctx["sync_s"]
+        row_extra = {}
+        sync_ul = sync_dl = 0.0
+        bcast_b = fh_parts = legs = None
+        top = None
+        if hier:
+            top = sync_step.fire_top((step + 1) // self.period)
+            row_extra = {"tier": int(top)}
+            if self.ledger is not None:
+                # measure the cascade's REAL per-boundary payloads and
+                # re-price the whole boundary from the actual bit counts
+                (sync_ul, sync_dl, sync_s, bcast_b, legs,
+                 row_bits) = self._measure_sync_hier(state, hbufs, top)
+                row_extra.update(row_bits)
+            else:
+                sync_ul, sync_dl = self._count_sync_hier(top)
+                sync_s += self._hier_sync_extra_s(top)
+        elif self.ledger is not None:
+            # measure the REAL fronthaul payloads this sync sends and
+            # re-price θ^U/θ^D from the actual bit counts
+            ul_b, dl_b = self._probe(state)
+            ul_b, dl_b = np.asarray(ul_b, np.float64), float(dl_b)
+            sync_ul, sync_dl = self._count_sync_measured(ul_b, dl_b)
+            aux = self._latency_aux()
+            # the post-consensus SBS->MU broadcast carries the ACTUAL
+            # consensus payload (dl_b bits), not the static per-iteration
+            # sbs_dl estimate: re-price each cluster's broadcast leg from
+            # its realized DL rate and charge the access link for the real
+            # bits; clusters mobility has emptied report dl_rate=inf (no
+            # broadcast time, no audience): charge neither time nor bits
+            # for them
+            finite = np.isfinite(aux["dl_rates"])
+            t_bcast = np.where(finite, dl_b / aux["dl_rates"], 0.0)
+            n_bcast = int(finite.sum())
+            if n_bcast:
+                bcast_b = self.ledger.record(
+                    "sbs_dl", n_bcast * dl_b, events=n_bcast)
+                self._bits_access += bcast_b
+            sync_s = float(
+                (ul_b.max() + dl_b) / aux["fh_rate"]
+                + (t_bcast[finite].max() if n_bcast else 0.0)
+            )
+            row_extra = {"bits_sbs_ul": float(ul_b.sum()),
+                         "bits_mbs_dl": dl_b,
+                         "bits_sync_bcast": n_bcast * dl_b}
+            if self.obs.enabled:
+                # viz-only leg durations; sync_s itself stays the single
+                # fused expression above (bit-identity)
+                fh_parts = (
+                    float(ul_b.max()) / aux["fh_rate"],
+                    dl_b / aux["fh_rate"],
+                    float(t_bcast[finite].max()) if n_bcast else 0.0,
+                )
+        else:
+            sync_ul, sync_dl = self._count_sync(N if N is not None else 1)
+        return (top, sync_s, sync_ul, sync_dl, bcast_b, fh_parts, legs,
+                row_extra)
+
     def _run_lockstep(
         self, state, train_step, sync_step, batches, num_steps, on_step,
         *, deadline: bool,
@@ -1316,135 +1380,84 @@ class SimEngine:
         hier = bool(getattr(sync_step, "hier", False))
         hbufs = sync_step.init_bufs(state) if hier else None
         for step in range(num_steps):
-            if step % H == 0:
-                # _round_ctx draws the slot sources itself (residency runs)
-                # so compute pricing can follow the resident shards; the
-                # virtual clock feeds the diurnal availability curve
-                self._vt = t
-                ctx = self._round_ctx(deadline)
-                if self._rounds_seen is not None:
-                    src = ctx.get("src")
-                    if src is not None:
-                        part = src[:, 0] >= 0
-                    elif ctx["keep_clusters"] is not None:
-                        part = np.asarray(ctx["keep_clusters"], bool)
+            with self.obs.host_span("hfl.step", step=step):
+                if step % H == 0:
+                    # _round_ctx draws the slot sources itself (residency
+                    # runs) so compute pricing can follow the resident
+                    # shards; the virtual clock feeds the diurnal
+                    # availability curve
+                    with self.obs.host_span("hfl.round"):
+                        self._vt = t
+                        ctx = self._round_ctx(deadline)
+                    if self._rounds_seen is not None:
+                        src = ctx.get("src")
+                        if src is not None:
+                            part = src[:, 0] >= 0
+                        elif ctx["keep_clusters"] is not None:
+                            part = np.asarray(ctx["keep_clusters"], bool)
+                        else:
+                            part = np.ones(N, bool)
+                        self._rounds_seen += 1
+                        self._rounds_part += part
+                        self.obs.health.ingest_round(part, t=t)
+                with self.obs.host_span("hfl.batch"):
+                    if self.residency is not None:
+                        batch, keep = self._gather_batch(next(it),
+                                                         ctx["src"])
                     else:
-                        part = np.ones(N, bool)
-                    self._rounds_seen += 1
-                    self._rounds_part += part
-                    self.obs.health.ingest_round(part, t=t)
-            if self.residency is not None:
-                batch, keep = self._gather_batch(next(it), ctx["src"])
-            else:
-                batch = self._apply_participation(next(it), ctx["mask"])
-                keep = ctx["keep_clusters"]
-            with self.obs.host_span("train_step"):
-                new_state, loss = train_step(state, batch)
-            if keep is not None:
-                state = _merge_clusters(state, new_state, keep)
-            else:
-                state = new_state
-            t_iter0 = t
-            t += ctx["iter_s"]
-            ul_b, dl_b = self._count_train(
-                ctx["participants"],
-                ctx.get("active_clusters", N if N is not None else 1))
-            if self.obs.enabled:
-                self._trace_train_step(step, t_iter0, ctx, ul_b, dl_b)
-            if self._record or self.obs.health.enabled:
-                loss_mean = float(jnp.mean(loss))
-                self.obs.health.ingest_loss(loss_mean, t=t)
-                if self._record:
-                    trace.add(kind="train", t=t, step=step, loss=loss_mean,
-                              dropped=ctx["dropped"])
-            if (step + 1) % H == 0:
-                sync_s = ctx["sync_s"]
-                row_extra = {}
-                sync_ul = sync_dl = 0.0
-                bcast_b = fh_parts = legs = None
-                top = None
-                if hier:
-                    top = sync_step.fire_top((step + 1) // H)
-                    row_extra = {"tier": int(top)}
-                    if self.ledger is not None:
-                        # measure the cascade's REAL per-boundary payloads
-                        # (before the donating sync step consumes the
-                        # state) and re-price the whole boundary from the
-                        # actual bit counts
-                        (sync_ul, sync_dl, sync_s, bcast_b, legs,
-                         row_bits) = self._measure_sync_hier(
-                             state, hbufs, top)
-                        row_extra.update(row_bits)
-                    else:
-                        sync_ul, sync_dl = self._count_sync_hier(top)
-                        sync_s += self._hier_sync_extra_s(top)
-                elif self.ledger is not None:
-                    # measure the REAL fronthaul payloads this sync sends
-                    # (before the donating sync step consumes the state)
-                    # and re-price θ^U/θ^D from the actual bit counts
-                    ul_b, dl_b = self._probe(state)
-                    ul_b, dl_b = np.asarray(ul_b, np.float64), float(dl_b)
-                    sync_ul, sync_dl = self._count_sync_measured(ul_b, dl_b)
-                    aux = self._latency_aux()
-                    # the post-consensus SBS->MU broadcast carries the
-                    # ACTUAL consensus payload (dl_b bits), not the static
-                    # per-iteration sbs_dl estimate: re-price each
-                    # cluster's broadcast leg from its realized DL rate
-                    # and charge the access link for the real bits
-                    # clusters mobility has emptied report dl_rate=inf
-                    # (no broadcast time, no audience): charge neither
-                    # time nor bits for them
-                    finite = np.isfinite(aux["dl_rates"])
-                    t_bcast = np.where(finite, dl_b / aux["dl_rates"], 0.0)
-                    n_bcast = int(finite.sum())
-                    if n_bcast:
-                        bcast_b = self.ledger.record(
-                            "sbs_dl", n_bcast * dl_b, events=n_bcast)
-                        self._bits_access += bcast_b
-                    sync_s = float(
-                        (ul_b.max() + dl_b) / aux["fh_rate"]
-                        + (t_bcast[finite].max() if n_bcast else 0.0)
-                    )
-                    row_extra = {"bits_sbs_ul": float(ul_b.sum()),
-                                 "bits_mbs_dl": dl_b,
-                                 "bits_sync_bcast": n_bcast * dl_b}
-                    if self.obs.enabled:
-                        # viz-only leg durations; sync_s itself stays the
-                        # single fused expression above (bit-identity)
-                        fh_parts = (
-                            float(ul_b.max()) / aux["fh_rate"],
-                            dl_b / aux["fh_rate"],
-                            float(t_bcast[finite].max()) if n_bcast else 0.0,
-                        )
+                        batch = self._apply_participation(next(it),
+                                                          ctx["mask"])
+                        keep = ctx["keep_clusters"]
+                with self.obs.host_span("hfl.train"):
+                    new_state, loss = train_step(state, batch)
+                if keep is not None:
+                    state = _merge_clusters(state, new_state, keep)
                 else:
-                    sync_ul, sync_dl = self._count_sync(
-                        N if N is not None else 1)
-                with self.obs.host_span("sync_step"):
-                    if hier:
-                        state, hbufs = sync_step(state, hbufs, top)
-                    elif stats_on:
-                        state, sstats = sync_step(state)
-                    else:
-                        state = sync_step(state)
-                t_sync0 = t
-                t += sync_s
+                    state = new_state
+                t_iter0 = t
+                t += ctx["iter_s"]
+                ul_b, dl_b = self._count_train(
+                    ctx["participants"],
+                    ctx.get("active_clusters", N if N is not None else 1))
                 if self.obs.enabled:
-                    self._trace_sync(step, t_sync0, sync_s, sync_ul,
-                                     sync_dl, bcast_b, fh_parts, row_extra,
-                                     legs=legs)
-                if stats_on:
-                    self.obs.health.ingest_sync_stats(sstats, t=t)
-                    self.obs.health.ingest_payload(sync_ul + sync_dl, t=t)
-                if self._record:
-                    trace.add(kind="sync", t=t, step=step,
-                              dropped=ctx["dropped"],
-                              deadline_s=ctx["deadline_s"],
-                              iter_s=ctx["iter_s"], sync_s=sync_s,
-                              **row_extra)
-                self._advance_fleet(H * ctx["iter_s"] + sync_s, now=t)
-            if on_step is not None:
-                on_step(step, state, loss)
-            self.obs.tick()
+                    self._trace_train_step(step, t_iter0, ctx, ul_b, dl_b)
+                if self._record or self.obs.health.enabled:
+                    loss_mean = float(jnp.mean(loss))
+                    self.obs.health.ingest_loss(loss_mean, t=t)
+                    if self._record:
+                        trace.add(kind="train", t=t, step=step, loss=loss_mean,
+                                  dropped=ctx["dropped"])
+                if (step + 1) % H == 0:
+                    with self.obs.host_span("hfl.sync"):
+                        (top, sync_s, sync_ul, sync_dl, bcast_b, fh_parts,
+                         legs, row_extra) = self._price_sync(
+                             step, state, hbufs, ctx, sync_step, hier, N)
+                        if hier:
+                            state, hbufs = sync_step(state, hbufs, top)
+                        elif stats_on:
+                            state, sstats = sync_step(state)
+                        else:
+                            state = sync_step(state)
+                    t_sync0 = t
+                    t += sync_s
+                    if self.obs.enabled:
+                        self._trace_sync(step, t_sync0, sync_s, sync_ul,
+                                         sync_dl, bcast_b, fh_parts, row_extra,
+                                         legs=legs)
+                    if stats_on:
+                        self.obs.health.ingest_sync_stats(sstats, t=t)
+                        self.obs.health.ingest_payload(sync_ul + sync_dl, t=t)
+                    if self._record:
+                        trace.add(kind="sync", t=t, step=step,
+                                  dropped=ctx["dropped"],
+                                  deadline_s=ctx["deadline_s"],
+                                  iter_s=ctx["iter_s"], sync_s=sync_s,
+                                  **row_extra)
+                    self._advance_fleet(H * ctx["iter_s"] + sync_s, now=t)
+                if on_step is not None:
+                    with self.obs.host_span("hfl.on_step"):
+                        on_step(step, state, loss)
+                self.obs.tick()
         self._finish_run()
         trace.meta.update(self._totals())
         return state, trace
@@ -1653,14 +1666,14 @@ class SimEngine:
                             lambda l: (l[n] if getattr(l, "ndim", 0) >= 2
                                        else l),
                             self._apply_participation(batch, mask))
-                    with self.obs.host_span("train_step"):
+                    with self.obs.host_span("hfl.train"):
                         state, loss = masked_train_step(state, batch_n, nj)
                 else:
                     if self.residency is not None:
                         batch, _keep = self._gather_batch(batch, src)
                     else:
                         batch = self._apply_participation(batch, mask)
-                    with self.obs.host_span("train_step"):
+                    with self.obs.host_span("hfl.train"):
                         new_state, loss = train_step(state, batch)
                     state = _take_cluster_row(state, new_state, n)
                 steps_done += 1
@@ -1677,7 +1690,7 @@ class SimEngine:
                                   name="train_dl", track=f"cluster{n}")
             bits = None
             sstats = None
-            with self.obs.host_span("sync_step"):
+            with self.obs.host_span("hfl.sync"):
                 # variants append (bits?, stats?) after the carried state
                 if dl_sparse:
                     out = sync_n(state, e_dl, nj, wj)
@@ -1872,7 +1885,7 @@ class SimEngine:
             loss = None
             for _h in range(H):
                 batch = self._apply_participation(next(it), mask)
-                with self.obs.host_span("train_step"):
+                with self.obs.host_span("hfl.train"):
                     new_state, loss = train_step(state, batch)
                 state = _merge_clusters(state, new_state, keep)
                 steps_done += 1
@@ -1882,7 +1895,7 @@ class SimEngine:
             # clock-free pushes, not barriers)
             utop = min(hier_fire_top(tiers, ev.round + 1), cut - 1)
             if utop >= 1:
-                with self.obs.host_span("sync_step"):
+                with self.obs.host_span("hfl.sync"):
                     state, bufs = unit_sync(state, bufs, u, utop)
                 s_ul, s_dl = self._count_sync_unit(utop, cut)
             loss_u = float(jnp.mean(loss) if jnp.ndim(loss) == 0
@@ -1911,7 +1924,7 @@ class SimEngine:
                     staleness = updates[tb][p] - last_pull[tb][a]
                     w = async_weight(staleness, tiers[tb].fanout,
                                      self.sim.staleness_exp)
-                    with self.obs.host_span("sync_step"):
+                    with self.obs.host_span("hfl.sync"):
                         state, bufs = push(state, bufs, tb, a, w)
                     updates[tb][p] += 1
                     last_pull[tb][a] = updates[tb][p]

@@ -337,6 +337,22 @@ def test_jit_sync_step_donates_state_buffers():
     assert not out.params["w"].is_deleted()
 
 
+def test_jit_dense_sync_returns_untouched_buffers_without_copies():
+    # dense averaging reads w_ref only for its dtype and leaves opt, eps and
+    # e alone: each donated buffer must come back as itself, not as a copy
+    hfl = HFLConfig(num_clusters=2, mus_per_cluster=1, period=1,
+                    sync_mode="dense")
+    params = {"w": jnp.zeros((64,), jnp.float32),
+              "v": jnp.zeros((8, 64), jnp.float32)}
+    state = hfl_init(params, SGDM(momentum=0.9), hfl)
+    sync = jit_sync_step(make_sync_step(hfl, mesh=None))
+    text = sync.lower(state).compile().as_text()
+    entry = text[text.index("\nENTRY"):]
+    assert " copy(" not in entry
+    n_buffers = len(jax.tree.leaves(state))
+    assert entry.count(" parameter(") == n_buffers
+
+
 # ---------------------------------------------------------------------------
 # scale-100k sampling scenario
 # ---------------------------------------------------------------------------
