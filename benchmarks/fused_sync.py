@@ -4,7 +4,9 @@ Grid: {sparse, quantized_sparse} x {paper-fig5 fronthaul φ=0.9, headline
 compression φ=0.99}, three sync paths each:
 
   * ``leaf``       — legacy per-leaf Ω (60 top-k / 60 scatter launches)
-  * ``flat/topk``  — PR 1's whole-model Ω via whole-vector ``lax.top_k``
+  * ``flat/topk``  — whole-model Ω applied as a mask, its exact top-k set
+                     found by a counting radix select (no ``top_k``,
+                     no scatter-add)
   * ``flat/fused`` — the ``kernels/fused_sync`` path: batched threshold →
                      compact → small-top-k finisher, bit-identical Ω
                      selection to ``topk`` at 2 top-k + 2 scatter-add

@@ -38,7 +38,7 @@ from __future__ import annotations
 
 import warnings
 from dataclasses import dataclass
-from functools import partial
+from functools import partial, reduce
 from typing import Any, Callable, NamedTuple, Optional
 
 import jax
@@ -53,8 +53,10 @@ from repro.utils import jaxcompat
 
 def _count_build(kind: str, **labels) -> None:
     """Build-time bookkeeping into the ambient metrics registry: which
-    step builders ran, under which mode/layout/impl — the builders have no
-    telemetry handle to thread, and build time is off the hot path."""
+    step builders ran, under which mode/layout/impl and, for a sync, in
+    which form it applies Ω (``mask``, ``payload`` or ``none``) — the
+    builders have no telemetry handle to thread, and build time is off
+    the hot path."""
     reg = current_registry()
     if reg.enabled:
         reg.counter(f"hfl.{kind}_builds").inc(**labels)
@@ -184,11 +186,12 @@ def _wire_round(x, fmt: str):
         and the quantization error lands in the same ``eps``/``e`` error
         buffers as the sparsification error.
 
-    On a 1-D payload this is the single-cluster case of
-    ``_wire_round_rows`` (the last-axis q8 scale IS the whole-payload
+    On a 1-D payload this is the single-row case of
+    ``_wire_round_pieces`` (the row's q8 scale IS the whole-payload
     scale), so it simply delegates — one copy of the wire rule.
     """
-    return _wire_round_rows(x, fmt)
+    with jax.named_scope("sync.compact"):
+        return _wire_round_pieces([x[None]], fmt)[0][0]
 
 
 def _wire_round_rows(x, fmt: str):
@@ -197,13 +200,27 @@ def _wire_round_rows(x, fmt: str):
     bit-identical to looping ``_wire_round`` over rows (the fused sync
     batches the N uplink hops). Wire rounding is part of ``sync.compact``."""
     with jax.named_scope("sync.compact"):
-        if fmt == "bf16":
-            return x.astype(jnp.bfloat16).astype(jnp.float32)
-        if fmt == "q8":
-            amax = jnp.max(jnp.abs(x), axis=-1, keepdims=True)
-            scale = jnp.where(amax > 0, amax / jnp.float32(127.0),
-                              jnp.float32(1.0))
-            return jnp.clip(jnp.round(x / scale), -127.0, 127.0) * scale
+        return _wire_round_pieces([x], fmt)[0]
+
+
+def _wire_round_pieces(pieces, fmt: str):
+    """Wire rounding of vectors held as pieces [R, ...]: row r of every
+    piece, concatenated, is one vector, so the q8 scale is ``max|·|`` over
+    that row of all the pieces (a model's leaves in the local sync, one
+    [R, k] payload elsewhere). Callers open ``sync.compact``."""
+    if fmt == "bf16":
+        return [x.astype(jnp.bfloat16).astype(jnp.float32) for x in pieces]
+    if fmt == "q8":
+        amax = reduce(jnp.maximum, [
+            jnp.max(jnp.abs(x), axis=tuple(range(1, x.ndim)))
+            for x in pieces])
+        scale = jnp.where(amax > 0, amax / jnp.float32(127.0),
+                          jnp.float32(1.0))
+        out = []
+        for x in pieces:
+            sc = scale.reshape((-1,) + (1,) * (x.ndim - 1))
+            out.append(jnp.clip(jnp.round(x / sc), -127.0, 127.0) * sc)
+        return out
     raise ValueError(fmt)
 
 
@@ -253,62 +270,91 @@ def _flat_sync_stats(wn, new_eps, new_e, new_wref, d, ul_idx, dl_idx):
 
 def _make_flat_local_sync(hfl_cfg, wire, collect_stats: bool = False):
     """Single-process whole-vector sync (mesh=None): the cluster axis is a
-    leading array axis and the cross-pod exchange is a local mean."""
+    leading array axis and the cross-pod exchange is a local mean.
+
+    Nothing crosses a wire here, so Ω is applied as a mask, with ``where``,
+    leaf by leaf: ``sp.omega_masks`` selects over the whole model vector
+    (every leaf's row, concatenated in ``fl.pack``'s order), and neither
+    the flat vectors nor the (values, indices) payload that a real
+    exchange ships are formed. The exact ``topk`` selection takes no sort
+    (a counting radix select). Wire rounding of the masked leaves equals
+    rounding the payload (bf16 is elementwise; q8's scale is ``max|·|``
+    over the kept entries, which the zeros cannot raise). Only
+    ``collect_stats`` forms the index sets, in index order."""
     impl = hfl_cfg.omega_impl
+    t1 = hfl_cfg.tiers[1]
 
     def flat_sync(state: HFLState):
         N = hfl_cfg.num_clusters
-        with jax.named_scope("sync.select"):
-            wref, ref_spec = fl.pack(state.w_ref)
-            e, _ = fl.pack(state.e)
-        p_spec = fl.spec_of_stacked(state.params)
-        Q = ref_spec.total
 
-        # --- SBS side: drift + discounted error, whole-vector top-k uplink
-        #     (Alg.5 l.24-27, Ω over V ∈ R^Q) ---
-        s, eps_spec = _pack_drift(state, hfl_cfg.tiers[1].beta_up)  # [N, Q]
-        sents, new_eps, ul_idx = [], [], []
-        for n in range(N):  # static unroll; N is small
-            with jax.named_scope("sync.select"):
-                s_n = s[n]
-            vals, idx = sp.pack_phi(s_n, hfl_cfg.tiers[1].phi_up, impl=impl)
-            if wire:
-                vals = _wire_round(vals, wire)
-            sent = sp.unpack_topk(vals, idx, Q)
-            sents.append(sent)
-            with jax.named_scope("sync.merge"):
-                new_eps.append(s[n] - sent)
-            ul_idx.append(idx)
-
-        # --- MBS side: consensus + discounted error + top-k downlink ---
-        with jax.named_scope("sync.exchange"):
-            mean = sum(sents) / N
+        # --- SBS side: drift + discounted error, whole-vector Ω uplinks
+        #     (Alg.5 l.24-27, Ω over V ∈ R^Q), all N rows in each pass ---
+        s = _drift_leaves(state, t1.beta_up)  # [N, *leaf] each
         with jax.named_scope("sync.select"):
-            delta = mean + hfl_cfg.tiers[1].beta_down * e
-        dvals, didx = sp.pack_phi(delta, hfl_cfg.tiers[1].phi_down, impl=impl)
-        if wire:
-            dvals = _wire_round(dvals, wire)
-        d = sp.unpack_topk(dvals, didx, Q)
+            ul_masks = sp.omega_masks(s, t1.phi_up, impl=impl)
         with jax.named_scope("sync.merge"):
-            new_e = delta - d
-            new_wref = wref + d
+            sent = [jnp.where(m, x, 0.0) for x, m in zip(s, ul_masks)]
+        if wire:
+            with jax.named_scope("sync.compact"):
+                sent = _wire_round_pieces(sent, wire)
+        with jax.named_scope("sync.merge"):
+            new_eps = [x - y for x, y in zip(s, sent)]
+
+        # --- MBS side: consensus + discounted error + Ω downlink ---
+        with jax.named_scope("sync.exchange"):
+            mean = [sum(y[n] for n in range(N)) / N for y in sent]
+        with jax.named_scope("sync.select"):
+            delta = [(m + t1.beta_down * e.astype(jnp.float32))[None]
+                     for m, e in zip(mean, jax.tree.leaves(state.e))]
+            dl_masks = sp.omega_masks(delta, t1.phi_down, impl=impl)
+        with jax.named_scope("sync.merge"):
+            d = [jnp.where(m, x, 0.0) for x, m in zip(delta, dl_masks)]
+        if wire:
+            with jax.named_scope("sync.compact"):
+                d = _wire_round_pieces(d, wire)
+        with jax.named_scope("sync.merge"):
+            new_e = [(x - y)[0] for x, y in zip(delta, d)]
+            new_wref = [w.astype(jnp.float32) + y[0]
+                        for w, y in zip(jax.tree.leaves(state.w_ref), d)]
 
             # --- clusters adopt the new reference (Alg.5 l.33/43) ---
-            new_wn = jnp.broadcast_to(new_wref[None], (N, Q))
-            eps_stacked = jnp.stack(new_eps)
             new_state = state._replace(
-                params=fl.unpack_stacked(new_wn, p_spec),
-                w_ref=fl.unpack(new_wref, ref_spec),
-                eps=fl.unpack_stacked(eps_stacked, eps_spec),
-                e=fl.unpack(new_e, ref_spec),
+                params=_with_leaves(state.params, new_wref),
+                w_ref=_with_leaves(state.w_ref, new_wref),
+                eps=_with_leaves(state.eps, new_eps),
+                e=_with_leaves(state.e, new_e),
             )
         if not collect_stats:
             return new_state
+        Q = sum(x.size for x in new_e)
+        s_rows, ul_rows = _rows(s), _rows(ul_masks)
+        k_ul = sp.keep_count(Q, t1.phi_up)
+        ul_idx = jnp.stack([sp.compact_mask(s_rows[n], ul_rows[n], k_ul)[1]
+                            for n in range(N)])
+        _, dl_idx = sp.compact_mask(_rows(delta)[0], _rows(dl_masks)[0],
+                                    sp.keep_count(Q, t1.phi_down))
         wn, _ = fl.pack_stacked(state.params)
+        flat = lambda leaves: jnp.concatenate([x.reshape(-1) for x in leaves])
         return new_state, _flat_sync_stats(
-            wn, eps_stacked, new_e, new_wref, d, jnp.stack(ul_idx), didx)
+            wn, _rows(new_eps), flat(new_e), flat(new_wref), flat(d),
+            ul_idx, dl_idx)
 
     return flat_sync
+
+
+def _rows(pieces):
+    """Pieces [R, ...] -> [R, Q]: row r of every piece, concatenated."""
+    R = pieces[0].shape[0]
+    return jnp.concatenate([x.reshape(R, -1) for x in pieces], axis=1)
+
+
+def _with_leaves(tree, leaves):
+    """``tree``'s structure holding ``leaves``, each cast to the dtype of
+    the leaf it replaces and broadcast to its shape (a reference leaf to
+    the [N, ...] cluster rows)."""
+    return jax.tree.unflatten(jax.tree.structure(tree), [
+        jnp.broadcast_to(x.astype(o.dtype), o.shape)
+        for x, o in zip(leaves, jax.tree.leaves(tree))])
 
 
 def _flat_shard_sync(params, w_ref, eps, e, *, hfl_cfg, wire):
@@ -419,6 +465,21 @@ def _unpack_ref_outputs(new_wref, ref_spec, state: HFLState):
             lambda w, r: w.astype(r.dtype), wref_tree_f32, state.w_ref
         )
         return params, w_ref
+
+
+def _drift_leaves(state: HFLState, beta_s: float):
+    """The drift of ``_pack_drift``, s = wn - wref + β_s·eps, leaf by leaf
+    in the leaves' own shapes, each [N, *leaf] f32: what the mask-form
+    sync selects from and merges, with no packed copy. Part of
+    ``sync.select``."""
+    with jax.named_scope("sync.select"):
+        return [
+            (p.astype(jnp.float32) - w.astype(jnp.float32)[None])
+            + beta_s * ep.astype(jnp.float32)
+            for p, w, ep in zip(jax.tree.leaves(state.params),
+                                jax.tree.leaves(state.w_ref),
+                                jax.tree.leaves(state.eps))
+        ]
 
 
 def _pack_drift(state: HFLState, beta_s: float, *, shards: int = 1):
@@ -1165,7 +1226,7 @@ class HierSyncStep:
                 "omega_impl='fused' is depth-2 only; use 'topk'/'hist' "
                 "for deeper hierarchies")
         _count_build("sync_step", mode=hfl_cfg.sync_mode, layout="hier",
-                     impl=hfl_cfg.omega_impl)
+                     impl=hfl_cfg.omega_impl, omega="payload")
         self.cfg = hfl_cfg
         self._wire = wire_format_of(hfl_cfg)
         self._fns = {}
@@ -1355,8 +1416,10 @@ def make_sync(plan: SyncPlan):
         (``_make_flat_sharded_sync``) — per-shard fused compaction, one
         all-gather of compacted candidates, no whole-vector
         materialization per device.
-      * other impls keep their historical paths (local whole-vector, or
-        the per-device "pod" shard_map on pod meshes).
+      * other impls: the local whole-vector sync, which applies Ω as a
+        mask and forms no payload (``topk`` by a counting radix select,
+        no sort), or the per-device "pod" shard_map on pod meshes, which
+        ships a ``lax.top_k`` payload.
 
     ``collect_stats=True`` makes the returned sync also return an in-jit
     learning-health statistics dict (``_flat_sync_stats``; the sync
@@ -1380,10 +1443,20 @@ def make_sync(plan: SyncPlan):
                 "depth > 2 hierarchies run the flat layout only")
         return HierSyncStep(hfl_cfg)
     mode = hfl_cfg.sync_mode
-    _count_build(
-        "sync_step", mode=mode,
-        layout=(layout or getattr(hfl_cfg, "sync_layout", "flat")),
-        impl=hfl_cfg.omega_impl)
+    layout = layout or getattr(hfl_cfg, "sync_layout", "flat")
+    has_pod = mesh is not None and "pod" in mesh.axis_names
+    flat_shards = int(getattr(hfl_cfg, "flat_shards", 1))
+    # Ω is a mask where the exchange is local (``_make_flat_local_sync``)
+    # and a (values, indices) payload wherever one is shipped
+    if mode == "dense":
+        omega_form = "none"
+    elif (not has_pod and layout == "flat" and flat_shards == 1
+          and hfl_cfg.omega_impl != "fused"):
+        omega_form = "mask"
+    else:
+        omega_form = "payload"
+    _count_build("sync_step", mode=mode, layout=layout,
+                 impl=hfl_cfg.omega_impl, omega=omega_form)
     if mode == "dense":
         N = hfl_cfg.num_clusters
 
@@ -1431,11 +1504,8 @@ def make_sync(plan: SyncPlan):
     wire = wire_format_of(hfl_cfg)
     if mode not in ("sparse", "quantized_sparse"):
         raise ValueError(mode)
-    layout = layout or getattr(hfl_cfg, "sync_layout", "flat")
     if layout not in ("flat", "leaf"):
         raise ValueError(layout)
-
-    has_pod = mesh is not None and "pod" in mesh.axis_names
 
     def _no_stats(path: str):
         if collect_stats:
@@ -1446,7 +1516,6 @@ def make_sync(plan: SyncPlan):
     if not has_pod:
         # Single-pod / CPU path: emulate the cluster axis locally. The
         # protocol still follows Alg.5 exactly; the "exchange" is a local sum.
-        flat_shards = int(getattr(hfl_cfg, "flat_shards", 1))
         if layout == "flat":
             fused = hfl_cfg.omega_impl == "fused"
             if mesh is not None and fused:

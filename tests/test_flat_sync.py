@@ -1,6 +1,8 @@
 """Flat-buffer whole-model sync engine: equivalence vs the leaf-wise
 reference path, flatten round-trips, and regressions for the zero-vector
 hist threshold and the dense-sync buffer-dtype drift."""
+import dataclasses
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -8,8 +10,10 @@ import pytest
 
 from repro.configs.base import HFLConfig, ModelConfig
 from repro.core import sparsify as sp
-from repro.core.hfl import hfl_init, make_sync_step
+from repro.core.hfl import (SyncPlan, _pack_drift, _wire_round, hfl_init,
+                            make_sync, make_sync_step, wire_format_of)
 from repro.models.transformer import init_model
+from repro.obs.metrics import MetricsRegistry, use_registry
 from repro.optim import SGDM
 from repro.utils import flatten as fl
 
@@ -104,6 +108,116 @@ def test_flat_equals_leaf_on_single_leaf_model(mode):
                         jax.tree.leaves(getattr(out_flat, name))):
             np.testing.assert_array_equal(np.asarray(a), np.asarray(b),
                                           err_msg=name)
+
+
+def test_flat_equals_leaf_on_single_leaf_model_q8():
+    """The q8 wire too: its scale is max|·| over the kept entries, whether
+    they ride a payload (leaf) or stay in place under a mask (flat)."""
+    N, Q = 3, 512
+    hfl = HFLConfig(num_clusters=N, mus_per_cluster=1, period=1,
+                    sync_mode="quantized_sparse", wire_format="q8",
+                    phi_sbs_ul=0.9, phi_mbs_dl=0.8, beta_s=0.5, beta_m=0.2)
+    params = {"w": jax.random.normal(jax.random.PRNGKey(0), (Q,))}
+    state = hfl_init(params, SGDM(), hfl)
+    noise = lambda i, s: s * jax.random.normal(jax.random.PRNGKey(i), (N, Q))
+    state = state._replace(params={"w": state.params["w"] + noise(1, 0.1)},
+                           eps={"w": noise(2, 0.01)},
+                           e={"w": noise(3, 0.01)[0]})
+    out_leaf = make_sync_step(hfl, mesh=None, layout="leaf")(state)
+    out_flat = make_sync_step(hfl, mesh=None, layout="flat")(state)
+    for name in ("params", "w_ref", "eps", "e"):
+        np.testing.assert_array_equal(
+            np.asarray(getattr(out_leaf, name)["w"]),
+            np.asarray(getattr(out_flat, name)["w"]), err_msg=name)
+
+
+def _payload_sync(state, hfl):
+    """The mesh-free flat sync with Ω as a payload, as it was built before
+    the local exchange applied it as a mask: ``lax.top_k`` values and
+    indices per hop (``pack_topk``), wire-rounded, scattered back
+    (``unpack_topk``)."""
+    wire, t1, N = wire_format_of(hfl), hfl.tiers[1], hfl.num_clusters
+    wref, ref_spec = fl.pack(state.w_ref)
+    e, _ = fl.pack(state.e)
+    s, eps_spec = _pack_drift(state, t1.beta_up)
+    Q = ref_spec.total
+    sents = []
+    for n in range(N):
+        vals, idx = sp.pack_topk(s[n], sp.keep_count(Q, t1.phi_up))
+        if wire:
+            vals = _wire_round(vals, wire)
+        sents.append(sp.unpack_topk(vals, idx, Q))
+    delta = sum(sents) / N + t1.beta_down * e
+    dvals, didx = sp.pack_topk(delta, sp.keep_count(Q, t1.phi_down))
+    if wire:
+        dvals = _wire_round(dvals, wire)
+    d = sp.unpack_topk(dvals, didx, Q)
+    new_wref = wref + d
+    return state._replace(
+        params=fl.unpack_stacked(jnp.broadcast_to(new_wref[None], (N, Q)),
+                                 fl.spec_of_stacked(state.params)),
+        w_ref=fl.unpack(new_wref, ref_spec),
+        eps=fl.unpack_stacked(s - jnp.stack(sents), eps_spec),
+        e=fl.unpack(delta - d, ref_spec))
+
+
+@pytest.mark.parametrize("mode,wire", [("sparse", "bf16"),
+                                       ("quantized_sparse", "bf16"),
+                                       ("quantized_sparse", "q8")],
+                         ids=["sparse", "bf16", "q8"])
+def test_mask_sync_equals_payload_sync_on_bf16_ties(mode, wire):
+    """A multi-leaf bf16 model at its first sync (eps = e = 0): the drift
+    sits on bf16 grids, so many entries tie at the k-th magnitude. The
+    mask-form sync keeps the same entries as ``lax.top_k`` and lands on the
+    same state, to the bit, as the payload composition it replaced."""
+    hfl = HFLConfig(num_clusters=3, mus_per_cluster=1, period=1,
+                    sync_mode=mode, wire_format=wire, phi_sbs_ul=0.9,
+                    phi_mbs_dl=0.9, beta_s=0.5, beta_m=0.2)
+    cfg = dataclasses.replace(_tiny_cfg(), dtype="bfloat16")
+    state = hfl_init(init_model(jax.random.PRNGKey(0), cfg),
+                     SGDM(momentum=0.9), hfl)
+    keys = iter(jax.random.split(jax.random.PRNGKey(1),
+                                 len(jax.tree.leaves(state.params))))
+    state = state._replace(params=jax.tree.map(
+        lambda p: (p + 1e-3 * jax.random.normal(next(keys), p.shape)
+                   ).astype(p.dtype), state.params))
+    s, _ = _pack_drift(state, hfl.tiers[1].beta_up)
+    k = sp.keep_count(s.shape[1], hfl.tiers[1].phi_up)
+    a = np.abs(np.asarray(s))
+    kth = np.sort(a, axis=1)[:, -k]
+    assert ((a == kth[:, None]).sum(axis=1) > 50).all()  # ties at the cut
+    # op by op, as the leaf-equivalence tests run: under one jit the CPU
+    # compiler may fuse the q8 products into the mean's sums and round
+    # them differently
+    got = make_sync(SyncPlan.from_config(hfl))(state)
+    want = _payload_sync(state, hfl)
+    for name in ("params", "w_ref", "eps", "e"):
+        for a, b in zip(jax.tree.leaves(getattr(want, name)),
+                        jax.tree.leaves(getattr(got, name))):
+            assert a.dtype == b.dtype, name
+            np.testing.assert_array_equal(np.asarray(a, np.float32),
+                                          np.asarray(b, np.float32),
+                                          err_msg=name)
+
+
+@pytest.mark.parametrize("mode", ["sparse", "quantized_sparse"])
+def test_local_topk_sync_has_no_sort_gather_or_scatter(mode):
+    """The mesh-free topk sync applies Ω as a mask: no ``top_k`` (a full
+    sort on the chip), no sort, no gather of a payload, no scatter back;
+    and the build records that form in the metrics registry."""
+    import re
+
+    hfl = HFLConfig(num_clusters=2, mus_per_cluster=1, period=1,
+                    sync_mode=mode, phi_sbs_ul=0.9, phi_mbs_dl=0.9)
+    state = _multi_leaf_state(hfl)
+    reg = MetricsRegistry()
+    with use_registry(reg):
+        sync = make_sync(SyncPlan.from_config(hfl))
+    txt = str(jax.make_jaxpr(sync)(state))
+    for prim in ("top_k", "sort", "gather", "scatter", "scatter-add"):
+        assert not re.search(rf"\b{prim}\[", txt), prim
+    series = reg.snapshot()["hfl.sync_step_builds"]["series"]
+    assert [k for k in series if "omega=mask" in k] and len(series) == 1
 
 
 def test_flat_and_leaf_phi0_equal_dense_mean_multi_leaf():

@@ -105,7 +105,7 @@ def _strip(hlo_text):
 # another scope's (the local mean into the downlink drift) leaves no name
 @pytest.mark.parametrize("kind,scopes", [
     ("train", TRAIN_SCOPES + ("attention",)),
-    (("sparse", "topk"), ("sync.select", "sync.compact", "sync.merge")),
+    (("sparse", "topk"), ("sync.select", "sync.merge")),
     (("sparse", "fused"), ("sync.select", "sync.merge")),
     (("dense", "topk"), ("sync.exchange",)),
 ], ids=["train", "topk", "fused", "dense"])

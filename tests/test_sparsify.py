@@ -92,3 +92,63 @@ def test_pack_unpack_roundtrip():
     dense = sp.unpack_topk(vals, idx, 512)
     s, mask = sp.omega(x, 0.9)
     np.testing.assert_allclose(np.asarray(dense), np.asarray(s), rtol=1e-6)
+
+
+# ---------------------------------------------------------------------------
+# topk_masks: lax.top_k's set by a counting radix select, no sort
+# ---------------------------------------------------------------------------
+
+
+def _grid(rng, shape, levels=20, step=2.0 ** -8):
+    """bf16-representable values on a coarse grid: thousands of ties."""
+    return rng.integers(-levels, levels + 1, shape).astype(np.float32) * step
+
+
+def _normal(shape, seed=0):
+    return np.random.default_rng(seed).standard_normal(shape).astype(
+        np.float32)
+
+
+_TAIL = 3 * sp.TIE_BLOCK + 5  # a 1-D row that is not whole tie blocks
+
+# case -> (pieces [R, ...] sharing the row axis, k)
+_TOPK_CASES = {
+    "random_normal": lambda: ([_normal((2, 3, 40, 50))], 600),
+    "random_normal_pieces": lambda: (
+        [_normal((2, 2, 64, 32), 1), _normal((2, 7), 2),
+         _normal((2, 2, 1), 3), _normal((2, 9000), 4)], 1_325),
+    "bf16_grid_ties": lambda: (
+        [_grid(np.random.default_rng(5), (2, 4, 100, 250))], 10_000),
+    "all_zero_rows": lambda: ([np.zeros((2, 30, 40), np.float32)], 400),
+    "fewer_nonzeros_than_k": lambda: (
+        [np.where(np.arange(3000) % 997 == 3, -1.5, 0.0).astype(np.float32)
+         .reshape(1, 3000)], 100),
+    "k_is_1": lambda: ([_normal((3, 5000), 6)], 1),
+    "k_is_n": lambda: ([_grid(np.random.default_rng(7), (2, 5000))], 5000),
+    "subnormals_and_negative_zero": lambda: (
+        [np.resize(np.array([0.0, -0.0, 1e-45, -1e-45, 1e-40, -3e-39,
+                             1e-38, -2.0], np.float32), (2, 4000))], 1_700),
+    "length_not_whole_tie_blocks": lambda: (
+        [_grid(np.random.default_rng(8), (2, _TAIL), levels=6)],
+        _TAIL // 4),
+}
+
+
+@pytest.mark.parametrize("case", list(_TOPK_CASES))
+def test_topk_masks_match_lax_top_k(case):
+    """Exactly the set ``lax.top_k`` picks from each concatenated row (ties
+    at the k-th magnitude by lowest index), in the pieces' shapes."""
+    pieces, k = _TOPK_CASES[case]()
+    R = pieces[0].shape[0]
+    rows = np.concatenate([p.reshape(R, -1) for p in pieces], axis=1)
+    masks = sp.topk_masks([jnp.asarray(p) for p in pieces], k)
+    got = np.concatenate([np.asarray(m).reshape(R, -1) for m in masks], axis=1)
+    for r in range(R):
+        _, idx = jax.lax.top_k(jnp.abs(jnp.asarray(rows[r])), k)
+        want = np.zeros(rows.shape[1], bool)
+        want[np.asarray(idx)] = True
+        np.testing.assert_array_equal(got[r], want, err_msg=f"row {r}")
+    assert [m.shape for m in masks] == [p.shape for p in pieces]
+    if case in ("bf16_grid_ties", "length_not_whole_tie_blocks"):
+        kth = np.sort(np.abs(rows), axis=1)[:, -k]
+        assert ((np.abs(rows) == kth[:, None]).sum(axis=1) > 1000).all()
