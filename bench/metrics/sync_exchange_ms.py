@@ -1,7 +1,7 @@
 """Device self time of the scope ``sync.exchange`` per execution of the
 consensus sync program, in ms: combining the clusters' payloads (a local or
-dense mean, or the pod all-gather) (``bench/scopes.py``), averaged over
-chips."""
+dense mean, or the pod all-gather, whose collectives XLA leaves without
+their scope and ``bench/scopes.py`` charges here), averaged over chips."""
 from bench.scopes import layer_ms
 
 

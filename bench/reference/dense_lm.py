@@ -197,18 +197,33 @@ def unflat(vec, like):
 
 def top_k_part(x, k: int):
     """``x`` where it is among the k largest magnitudes, else 0. Ties at the
-    k-th magnitude go to the lower index. By sorting: the k-th largest
-    magnitude is the threshold, then the lowest indices among the entries
-    equal to it fill the rest."""
-    a = jnp.abs(x)
+    k-th magnitude go to the lower index. By counting, with no sort: |x|'s
+    f32 bits order as its values, so the k-th largest magnitude ``t`` is
+    the largest bit pattern that at least k entries reach, found one bit at
+    a time; the lowest indices among the entries equal to it then fill the
+    rest, up to the least index ``cut`` at which enough of them are in,
+    found by bisection. Each step is one pass of counting over ``x``."""
+    a = jax.lax.bitcast_convert_type(jnp.abs(x), jnp.int32)
     n = a.size
-    t = jnp.sort(a)[n - k]
+
+    def bit(i, t):
+        c = t | jnp.left_shift(jnp.int32(1), 30 - i)
+        return jnp.where(jnp.sum(a >= c) >= k, c, t)
+
+    t = jax.lax.fori_loop(0, 31, bit, jnp.int32(0))
     gt = a > t
     eq = a == t
     need = k - jnp.sum(gt)
     iota = jnp.arange(n, dtype=jnp.int32)
-    eq_idx = jnp.sort(jnp.where(eq, iota, n))
-    cut = eq_idx[jnp.maximum(need - 1, 0)]
+
+    def halve(_, lo_hi):
+        lo, hi = lo_hi
+        mid = lo + (hi - lo) // 2
+        enough = jnp.sum(eq & (iota <= mid)) >= need
+        return jnp.where(enough, lo, mid + 1), jnp.where(enough, mid, hi)
+
+    _, cut = jax.lax.fori_loop(0, max(n - 1, 1).bit_length(), halve,
+                               (jnp.int32(0), jnp.int32(n - 1)))
     keep = gt | (eq & (iota <= cut) & (need > 0))
     return jnp.where(keep, x, 0.0)
 
@@ -264,12 +279,16 @@ def readings(m: dict, t: dict, dep: dict, weights, pool, *, mm: str = "f32",
     the initial ``weights`` on the token batches ``pool`` [batch][cluster]
     (see ``bench/check.py`` for what each reading is).
 
-    Cluster n lives on ``devices[n % len(devices)]``. ``mm`` picks the
-    matmul precision (``f32``, or ``fp8`` for the control). ``fault`` plants
-    one fault for the harness's own checks: ``half_batch`` (each step sees
-    the first half of its rows), ``no_exchange`` (only cluster 0's payload
-    reaches the consensus), ``answer_altered`` (the consensus update of the
-    embedding is dropped).
+    Cluster n lives on ``devices[n % len(devices)]``; the consensus's own
+    flat vectors (initial weights, reference, downlink error) wait on the
+    host between uses and are computed on the first device, so that no chip
+    holds more than its clusters and one sync's working vectors.
+
+    ``mm`` picks the matmul precision (``f32``, or ``fp8`` for the
+    control). ``fault`` plants one fault for the harness's own checks:
+    ``half_batch`` (each step sees the first half of its rows),
+    ``no_exchange`` (only cluster 0's payload reaches the consensus),
+    ``answer_altered`` (the consensus update of the embedding is dropped).
     """
     devices = list(devices or jax.devices())
     N, H = dep["clusters"], t["period"]
@@ -285,8 +304,8 @@ def readings(m: dict, t: dict, dep: dict, weights, pool, *, mm: str = "f32",
            for p in params]
     q = sum(sizes)
     eps = [jax.device_put(jnp.zeros((q,), jnp.float32), d) for d in devs]
-    w0 = _flat(weights)
-    wref, e = w0, jnp.zeros((q,), jnp.float32)
+    w0 = np.asarray(_flat(weights))
+    wref, e = w0, np.zeros((q,), np.float32)
     out = {"loss": [], "grad": {}, "sync": {}, "change": {}}
     for step in range(max(3, H)):
         batch = pool[step % len(pool)]
@@ -328,21 +347,21 @@ def readings(m: dict, t: dict, dep: dict, weights, pool, *, mm: str = "f32",
 
 def _consensus(params, wref, eps, e, t, devs, q, emb, sizes, fault):
     """One sync over flat vectors; updates ``eps`` in place and returns the
-    new (reference, downlink error)."""
-    N = len(params)
+    new (reference, downlink error), on the host as ``wref`` and ``e`` are."""
+    N, root = len(params), devs[0]
     if t["sync_mode"] == "dense":
         total = None
         for n, p in enumerate(params):
             if fault == "no_exchange" and n > 0:
                 continue
-            pf = jax.device_put(_flat(p), devs[0])
+            pf = jax.device_put(_flat(p), root)
             total = pf if total is None else _add(total, pf)
         count = 1 if fault == "no_exchange" else N
-        new = _mix(total, count, e, 0.0)
+        new = _mix(total, count, jax.device_put(e, root), 0.0)
         if fault == "answer_altered":
             off = sum(sizes[:emb])
             new = new.at[off:off + sizes[emb]].set(wref[off:off + sizes[emb]])
-        return new, e
+        return np.asarray(new), e
     if t["sync_mode"] != "sparse":  # quantized_sparse needs wire rounding
         raise ValueError(f"no reference for sync_mode {t['sync_mode']!r}")
     k_up, k_dn = keep_count(q, t["phi_up"]), keep_count(q, t["phi_down"])
@@ -354,11 +373,15 @@ def _consensus(params, wref, eps, e, t, devs, q, emb, sizes, fault):
         del s
         if fault == "no_exchange" and n > 0:
             continue
-        sent = jax.device_put(sent, devs[0])
+        sent = jax.device_put(sent, root)
         total = sent if total is None else _add(total, sent)
-    delta = _mix(total, N, e, t["beta_down"])
+    del sent
+    delta = _mix(total, N, jax.device_put(e, root), t["beta_down"])
+    del total
     dd = _top(delta, k_dn)
     if fault == "answer_altered":
         off = sum(sizes[:emb])
         dd = dd.at[off:off + sizes[emb]].set(0.0)
-    return _add(wref, dd), _sub(delta, dd)
+    e = np.asarray(_sub(delta, dd))
+    del delta
+    return np.asarray(_add(jax.device_put(wref, root), dd)), e

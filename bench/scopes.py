@@ -17,8 +17,12 @@ Everything is clipped to the window span (``bench.window``):
   ``op_name`` of its ``;``-list to the first layer of ``LAYERS`` whose
   names all appear on the path, as a whole component or inside a
   transform's parentheses (``vmap(train.grad)``); the last component, the
-  primitive, never matches. What no layer takes is charged to ``-``; the
-  sub-layers of ``SUBLAYERS`` are charged beside the layers, not instead.
+  primitive, never matches. A collective that XLA left without an
+  ``op_name`` is charged to ``EXCHANGE``: on a v5e the pod all-gather is
+  rewritten into an all-reduce of a zero-padded buffer that keeps none,
+  and the sync's exchange is the only collective the programs run. What
+  no layer takes is charged to ``-``; the sub-layers of ``SUBLAYERS`` are
+  charged beside the layers, not instead.
 * ``idle``: per chip, the device's idle time split at the boundaries of the
   program's host spans (``hfl.*``), each piece charged to the innermost
   span open over it (``-`` where none is), and the number of ``hfl.run``
@@ -42,6 +46,8 @@ import os
 import re
 from pathlib import Path
 
+from bench.trace_reduce import is_collective
+
 WINDOW_SPAN = "bench.window"
 SPAN_PREFIX = "hfl."
 RUN_SPAN = "hfl.run"
@@ -64,6 +70,7 @@ LAYERS = (
     ("sync.merge", ("sync.merge",)),
 )
 SUBLAYERS = (("attention", ("attention",)),)
+EXCHANGE = "sync.exchange"
 _FINGERPRINT = re.compile(r"\(\d+\)$")
 _TOKEN = re.compile(r"[^()]+")
 
@@ -209,6 +216,14 @@ def layer_of(op_name: str, layers=LAYERS) -> str:
     return UNSCOPED
 
 
+def op_layer(op_name: str | None, event: str, layers=LAYERS) -> str:
+    """The layer of an ``XLA Ops`` event named ``event`` whose op path is
+    ``op_name`` (None where XLA left it none)."""
+    if op_name:
+        return layer_of(op_name, layers)
+    return EXCHANGE if is_collective(event) else UNSCOPED
+
+
 # ---------------------------------------------------------------------------
 # The reduction
 # ---------------------------------------------------------------------------
@@ -343,22 +358,21 @@ def reduce_scopes(path, layers=LAYERS) -> dict:
             op = tf_ops.get(mid)
             i = bisect.bisect_right(starts, s) - 1
             mod = mods[i][2] if i >= 0 and s < mods[i][1] else UNSCOPED
-            ops.append((s, e, (mod, op)))
+            ops.append((s, e, (mod, op, emeta.get(mid, ("",))[0])))
             busy.append((max(s, w0), min(e, w1)))
         tagged = untagged = 0
-        for ps, (mod, op) in _self_times(ops, w0, w1):
+        for ps, (mod, op, event) in _self_times(ops, w0, w1):
             prog = programs.setdefault(mod, {"count": 0, "layers": {},
                                              "sublayers": {}})
+            layer = op_layer(op, event, layers)
             if op:
                 tagged += ps
-                layer = layer_of(op, layers)
                 for sub, need in SUBLAYERS:
                     if path_names(op).issuperset(need):
                         prog["sublayers"][sub] = (
                             prog["sublayers"].get(sub, 0) + ps / 1e3)
             else:
                 untagged += ps
-                layer = UNSCOPED
             prog["layers"][layer] = prog["layers"].get(layer, 0) + ps / 1e3
         kinds = {mod: k for mod, prog in programs.items() for k in LAUNCH_SPANS
                  if any(lay.startswith(k + ".") for lay in prog["layers"])}

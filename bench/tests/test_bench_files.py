@@ -2,6 +2,7 @@
 benchmark's contract."""
 import importlib
 import json
+import math
 import re
 from pathlib import Path
 
@@ -55,6 +56,22 @@ def test_cell_files_load_by_name(cell):
                      .read_text())["limits"]
     assert set(lim) == {"loss_gap", "grad_gap", "sync_gap", "change_gap"}
     assert cell["chips"] in (1, 4)
+
+
+@pytest.mark.parametrize("cell", SPEC["workloads"], ids=lambda e: e["name"])
+def test_deployment_fills_the_cells_chips(cell):
+    """A cell's configuration states the chips the cell asks for, and a
+    four-chip cell's mesh holds one cluster on each chip."""
+    entry = {c["name"]: c for c in SPEC["configs"]}[cell["config"]]
+    dep = json.loads((ROOT / entry["file"]).read_text())["deployment"]
+    assert dep["chips"] == cell["chips"]
+    if cell["chips"] == 1:
+        assert dep["mesh"] is None
+        return
+    mesh = dep["mesh"]
+    assert mesh["axes"] == ["pod", "data", "model"]
+    assert math.prod(mesh["shape"]) == cell["chips"] == dep["clusters"]
+    assert mesh["shape"][0] == dep["clusters"]
 
 
 @pytest.mark.parametrize("metric", SPEC["end_to_end"] + SPEC["per_layer"],

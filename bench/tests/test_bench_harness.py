@@ -7,11 +7,11 @@ import subprocess
 import sys
 from pathlib import Path
 
-import jax
 import pytest
 
 from bench import run
 from repro.core import hfl
+from bench.tests.faults import PLANTED
 from bench.tests.tiny import SEED, TINY
 
 ROOT = Path(__file__).resolve().parents[2]
@@ -50,48 +50,10 @@ def test_sound_run_is_correct(cell, capsys):
     assert list(json.loads(line))[-1] == "checks"
 
 
-def _unchanged(make):
-    def build(loss_fn, opt, sched):
-        step = make(loss_fn, opt, sched)
-
-        def broken(state, batch):
-            _, losses = step(state, batch)
-            return state._replace(step=state.step + 1), losses
-        return broken
-    return build
-
-
-def _half_batch(make):
-    def build(loss_fn, opt, sched):
-        step = make(loss_fn, opt, sched)
-
-        def broken(state, batch):
-            half = jax.tree.map(lambda x: x[:, :x.shape[1] // 2], batch)
-            return step(state, half)
-        return broken
-    return build
-
-
-def _answer_altered(make):
-    def build(plan):
-        sync = make(plan)
-
-        def broken(state):
-            new = sync(state)
-            w = dict(new.w_ref)
-            w["embed"] = w["embed"] * 1.01
-            return new._replace(w_ref=w)
-        return broken
-    return build
-
-
 @pytest.mark.parametrize("cell", ONE_CHIP)
-@pytest.mark.parametrize("target,fault", [
-    ("make_cluster_train_step", _unchanged),
-    ("make_cluster_train_step", _half_batch),
-    ("make_sync", _answer_altered),
-], ids=["state_unchanged", "half_batch", "answer_altered"])
-def test_broken_path_is_not_correct(cell, target, fault, monkeypatch):
-    monkeypatch.setattr(hfl, target, fault(getattr(hfl, target)))
+@pytest.mark.parametrize("fault", list(PLANTED))
+def test_broken_path_is_not_correct(cell, fault, monkeypatch):
+    target, plant = PLANTED[fault]
+    monkeypatch.setattr(hfl, target, plant(getattr(hfl, target)))
     r = run.run(argv(cell, 0.2), require_tpu=False, overrides=TINY)
     assert not r["correct"], r["checks"]

@@ -83,6 +83,19 @@ def test_path_names_and_layers():
     assert scopes.layer_of("jit(flat_sync)/sync.selected/top_k") == "-"
 
 
+@pytest.mark.parametrize("op_name,event,layer", [
+    # XLA's rewrite of the pod all-gather keeps no op path
+    (None, "%all-reduce.1 = f32[68367156]{0} all-reduce(%dus.1)",
+     "sync.exchange"),
+    (None, "all-gather-start.2", "sync.exchange"),
+    (None, "%copy.3 = f32[8]{0} copy(%p)", "-"),
+    (None, "%all-reduce-scatter-fusion = f32[8]{0} fusion(%p)", "-"),
+    ("jit(sparse_sync)/sync.merge/add:", "all-reduce.2", "sync.merge"),
+])
+def test_an_untagged_collective_is_the_exchange(op_name, event, layer):
+    assert scopes.op_layer(op_name, event) == layer
+
+
 def test_self_times_and_idle_split():
     ops = [(0, 100, "while"), (10, 30, "a"), (30, 60, "b"), (40, 50, "c"),
            (120, 150, "d")]
@@ -198,3 +211,54 @@ def test_the_program_spans_are_in_the_host_plane():
              for line in p.lines for ev in line.events}
     assert {"hfl.run", "hfl.step", "hfl.round", "hfl.batch", "hfl.train",
             "hfl.sync", "hfl.on_step"} <= names
+
+
+# a --trace 1 run of olmo1b-c4pod.sparse-h2 on four v5e chips: two HFL
+# periods, the pod sync's all-gathers rewritten into untagged all-reduces
+POD = ROOT / "bench/testdata/c4pod-sparse-h2.xplane.pb.gz"
+POD_MODULES = {"train": "jit_train_step", "sync": "jit_sparse_sync"}
+
+
+@pytest.fixture(scope="module")
+def pod_ctx():
+    red = trace_reduce.reduce_trace(POD)
+    return {"trace": red, "scopes": scopes.reduce_scopes(POD),
+            "modules": POD_MODULES}
+
+
+def test_pod_exchange_is_the_collectives_time(pod_ctx):
+    from bench.metrics import sync_exchange_ms
+
+    devs = pod_ctx["trace"]["devices"]
+    assert len(devs) == 4
+    per_sync = [d["collective_ns"] / d["modules"]["jit_sparse_sync"]["count"]
+                for d in devs]
+    coll_ms = sum(per_sync) / len(per_sync) / 1e6
+    assert coll_ms > 10.0
+    assert sync_exchange_ms.read(pod_ctx) == pytest.approx(coll_ms, rel=0.05)
+
+
+@pytest.mark.parametrize("name,lo,hi", [
+    ("sync_ms", 7270.0, 7274.0),
+    ("sync_select_ms", 3499.0, 3502.0),
+    ("sync_compact_ms", 910.0, 913.0),
+    ("sync_exchange_ms", 20.5, 21.7),
+    ("sync_merge_ms", 2773.0, 2776.0),
+    ("train_step_ms", 233.0, 233.6),
+])
+def test_readers_on_the_pod_trace(pod_ctx, name, lo, hi):
+    v = importlib.import_module(f"bench.metrics.{name}").read(pod_ctx)
+    assert lo <= v <= hi
+
+
+def test_pod_sync_layers_and_their_rest_add_up(pod_ctx):
+    read = lambda n: importlib.import_module(f"bench.metrics.{n}").read(
+        pod_ctx)
+    rests = [d["programs"]["jit_sparse_sync"]["layers"]["-"]
+             / d["programs"]["jit_sparse_sync"]["count"] / 1e6
+             for d in pod_ctx["scopes"]["devices"]]
+    rest = sum(rests) / len(rests)
+    layers = ["sync_select_ms", "sync_compact_ms", "sync_exchange_ms",
+              "sync_merge_ms"]
+    assert sum(read(n) for n in layers) + rest == pytest.approx(
+        read("sync_ms"), rel=1e-3)

@@ -17,10 +17,3 @@ TINY = {
                "change_gap": 0.02},
 }
 SEED = 3_000_000_001  # wider than 31 bits, as the driver's seeds are
-# four clusters, one per device on a (4,1,1) pod mesh
-POD = {
-    "cell": {"chips": 4},
-    "deployment": {"clusters": 4, "mus_per_cluster": 2, "chips": 4,
-                   "mesh": {"shape": [4, 1, 1],
-                            "axes": ["pod", "data", "model"]}},
-}
